@@ -106,13 +106,8 @@ def loss_fn(spec: EncDecSpec, params: Dict, X: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def _pinv(G: jnp.ndarray) -> jnp.ndarray:
-    """Moore-Penrose with a 1e-6 relative cutoff. jax >= 0.4.32 spells the
-    cutoff ``rtol`` and deprecates ``rcond`` (a DeprecationWarning the CI
-    examples step escalates to an error); older jax only knows ``rcond``."""
-    try:
-        return jnp.linalg.pinv(G, rtol=1e-6)
-    except TypeError:
-        return jnp.linalg.pinv(G, rcond=1e-6)
+    """Moore-Penrose with a 1e-6 relative cutoff."""
+    return jnp.linalg.pinv(G, rtol=1e-6)
 
 
 def sigma_B(spec: EncDecSpec, w: jnp.ndarray, X: jnp.ndarray,

@@ -6,7 +6,7 @@ online-softmax algorithm but XLA materializes each (block_q, block_kv) score
 tile and the f32 accumulator in HBM between loop steps (visible in the
 roofline memory term). The forward kernel keeps q-tile, running
 max/denominator and the accumulator resident in VMEM for the whole KV sweep:
-HBM traffic drops to one read of Q/K/V + one write of O (+ the (B·H, S)
+HBM traffic drops to one read of Q/K/V + one write of O (+ the (B·H, S, 1)
 logsumexp row, the only residual the backward needs).
 
 Training support: ``flash_attention`` carries a :func:`jax.custom_vjp` with
@@ -57,44 +57,58 @@ def _kv_bounds(qi, block_q: int, block_kv: int, seq_len: int, causal: bool,
     return lo, hi
 
 
-def _tile_mask(q_ids, k_ids, causal: bool, window: int):
-    mask = jnp.ones((q_ids.shape[0], k_ids.shape[0]), jnp.bool_)
+def _tile_mask(q0, k0, block_q: int, block_kv: int, causal: bool,
+               window: int):
+    """(block_q, block_kv) validity of the score tile whose first query and
+    key positions are ``q0`` and ``k0``."""
+    q_ids = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
+    k_ids = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
+    mask = jnp.ones((block_q, block_kv), jnp.bool_)
     if causal:
-        mask &= k_ids[None, :] <= q_ids[:, None]
+        mask &= k_ids <= q_ids
     if window > 0:
-        mask &= k_ids[None, :] > q_ids[:, None] - window
+        mask &= k_ids > q_ids - window
     return mask
 
+
+def _mm(a, b, contract=(1, 0)):
+    """float32 product contracting ``a``'s axis ``contract[0]`` with ``b``'s
+    axis ``contract[1]`` (transposed operands ride the MXU, not a copy)."""
+    return jax.lax.dot_general(a, b, (((contract[0],), (contract[1],)),
+                                      ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+# Per-row softmax statistics (running max, denominator, logsumexp, Δ) are
+# (rows, 1) columns throughout: Mosaic lays out 2-D values only, and the
+# (B·H, S, 1) lse/Δ arrays give BlockSpecs whose last dim is the full one.
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
                   block_kv: int, seq_len: int, causal: bool, window: int,
                   scale: float):
     qi = pl.program_id(1)
     q = q_ref[...].astype(jnp.float32) * scale          # (bq, d)
-    q_ids = qi * block_q + jax.lax.iota(jnp.int32, block_q)
     lo, hi = _kv_bounds(qi, block_q, block_kv, seq_len, causal, window)
 
     def body(j, state):
         m, l, acc = state
-        k = pl.load(k_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-        s = q @ k.T                                     # (bq, bkv)
-        k_ids = j * block_kv + jax.lax.iota(jnp.int32, block_kv)
-        s = jnp.where(_tile_mask(q_ids, k_ids, causal, window), s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        k = k_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
+        s = _mm(q, k, (1, 1))                           # (bq, bkv)
+        s = jnp.where(_tile_mask(qi * block_q, j * block_kv, block_q,
+                                 block_kv, causal, window), s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=-1)
-        acc_new = acc * corr[:, None] + p @ v
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        acc_new = acc * corr + _mm(p, v)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
+    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((block_q, 1), jnp.float32)
     a0 = jnp.zeros((block_q, q_ref.shape[-1]), jnp.float32)
     m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, a0))
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     lse_ref[...] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
@@ -105,23 +119,20 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qi = pl.program_id(1)
     q = q_ref[...].astype(jnp.float32) * scale          # (bq, d)
     do = do_ref[...].astype(jnp.float32)                # (bq, d)
-    lse = lse_ref[...]                                  # (bq,)
-    delta = delta_ref[...]                              # (bq,)
-    q_ids = qi * block_q + jax.lax.iota(jnp.int32, block_q)
+    lse = lse_ref[...]                                  # (bq, 1)
+    delta = delta_ref[...]                              # (bq, 1)
     lo, hi = _kv_bounds(qi, block_q, block_kv, seq_len, causal, window)
 
     def body(j, dq):
-        k = pl.load(k_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-        s = q @ k.T
-        k_ids = j * block_kv + jax.lax.iota(jnp.int32, block_kv)
-        mask = _tile_mask(q_ids, k_ids, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dp = do @ v.T                                   # (bq, bkv)
-        ds = p * (dp - delta[:, None])
-        return dq + ds @ k
+        k = k_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
+        s = _mm(q, k, (1, 1))
+        mask = _tile_mask(qi * block_q, j * block_kv, block_q, block_kv,
+                          causal, window)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        dp = _mm(do, v, (1, 1))                         # (bq, bkv)
+        ds = p * (dp - delta)
+        return dq + _mm(ds, k)
     dq = jax.lax.fori_loop(
         lo, hi, body, jnp.zeros((block_q, q_ref.shape[-1]), jnp.float32))
     dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
@@ -134,7 +145,6 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ki = pl.program_id(1)
     k = k_ref[...].astype(jnp.float32)                  # (bkv, d)
     v = v_ref[...].astype(jnp.float32)
-    k_ids = ki * block_kv + jax.lax.iota(jnp.int32, block_kv)
     nq = seq_len // block_q
     # valid q blocks: q >= min(k) when causal; q < max(k) + window when
     # windowed (the exact per-element mask is applied inside the tile)
@@ -148,20 +158,19 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def body(j, state):
         dk, dv = state
-        q = pl.load(q_ref, (pl.dslice(j * block_q, block_q),
-                            slice(None))).astype(jnp.float32) * scale
-        do = pl.load(do_ref, (pl.dslice(j * block_q, block_q),
-                              slice(None))).astype(jnp.float32)
-        lse = pl.load(lse_ref, (pl.dslice(j * block_q, block_q),))
-        delta = pl.load(delta_ref, (pl.dslice(j * block_q, block_q),))
-        q_ids = j * block_q + jax.lax.iota(jnp.int32, block_q)
-        s = q @ k.T                                     # (bq, bkv)
-        mask = _tile_mask(q_ids, k_ids, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dv = dv + p.T @ do                              # (bkv, d)
-        dp = do @ v.T                                   # (bq, bkv)
-        ds = p * (dp - delta[:, None])
-        dk = dk + ds.T @ q                              # (bkv, d), q scaled
+        rows = pl.ds(j * block_q, block_q)
+        q = q_ref[rows, :].astype(jnp.float32) * scale
+        do = do_ref[rows, :].astype(jnp.float32)
+        lse = lse_ref[rows, :]                          # (bq, 1)
+        delta = delta_ref[rows, :]
+        s = _mm(q, k, (1, 1))                           # (bq, bkv)
+        mask = _tile_mask(j * block_q, ki * block_kv, block_q, block_kv,
+                          causal, window)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        dv = dv + _mm(p, do, (0, 0))                    # (bkv, d)
+        dp = _mm(do, v, (1, 1))                         # (bq, bkv)
+        ds = p * (dp - delta)
+        dk = dk + _mm(ds, q, (0, 0))                    # (bkv, d), q scaled
         return dk, dv
 
     z = jnp.zeros((block_kv, k_ref.shape[-1]), jnp.float32)
@@ -191,11 +200,11 @@ def _flash_fwd_call(q, k, v, causal, window, block_q, block_kv, interpret,
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q), lambda b, i: (b, i)),
+            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, S), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
@@ -214,7 +223,8 @@ def _flash_bwd_call(q, k, v, out, lse, g, causal, window, block_q, block_kv,
     # Δ = rowsum(dO ⊙ O): elementwise over (B·H, S, D), XLA fuses it — the
     # only O(S·D) extra HBM pass the backward needs.
     delta = jnp.sum(dof.astype(jnp.float32)
-                    * out.reshape(B * H, S, D).astype(jnp.float32), axis=-1)
+                    * out.reshape(B * H, S, D).astype(jnp.float32), axis=-1,
+                    keepdims=True)
     kw = dict(block_q=block_q, block_kv=block_kv, seq_len=S, causal=causal,
               window=window, scale=scale)
     row_specs = [
@@ -222,14 +232,14 @@ def _flash_bwd_call(q, k, v, out, lse, g, causal, window, block_q, block_kv,
         pl.BlockSpec((None, S, D), lambda b, i: (b, 0, 0)),   # k
         pl.BlockSpec((None, S, D), lambda b, i: (b, 0, 0)),   # v
         pl.BlockSpec((None, S, D), lambda b, i: (b, 0, 0)),   # do
-        pl.BlockSpec((None, S), lambda b, i: (b, 0)),         # lse
-        pl.BlockSpec((None, S), lambda b, i: (b, 0)),         # delta
+        pl.BlockSpec((None, S, 1), lambda b, i: (b, 0, 0)),   # lse
+        pl.BlockSpec((None, S, 1), lambda b, i: (b, 0, 0)),   # delta
     ]
     dq_specs = list(row_specs)
     dq_specs[0] = pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0))
     dq_specs[3] = pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0))
-    dq_specs[4] = pl.BlockSpec((None, block_q), lambda b, i: (b, i))
-    dq_specs[5] = pl.BlockSpec((None, block_q), lambda b, i: (b, i))
+    dq_specs[4] = pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0))
+    dq_specs[5] = pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kw),
         grid=(B * H, S // block_q),
@@ -268,7 +278,7 @@ def _flash_diff(q, k, v, causal, window, block_q, block_kv, interpret):
 def _flash_diff_fwd(q, k, v, causal, window, block_q, block_kv, interpret):
     out, lse = _flash_fwd_call(q, k, v, causal, window, block_q, block_kv,
                                interpret, with_lse=True)
-    # residuals: inputs + output + the (B·H, S) logsumexp — the score matrix
+    # residuals: inputs + output + the (B·H, S, 1) logsumexp — the score matrix
     # is recomputed tile-by-tile in VMEM, never stored
     return out, (q, k, v, out, lse)
 

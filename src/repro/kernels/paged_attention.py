@@ -169,6 +169,29 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, cur_pos, *,
     )(page_table, cur_pos, q, k_pool, v_pool)
 
 
+def _sharded_paged_decode(q, k_pool, v_pool, page_table, cur_pos, *, mesh,
+                          axes, interpret: bool) -> jnp.ndarray:
+    """The Pallas kernel under ``shard_map``: slots split over the mesh's
+    data ``axes`` (zero-padded to a multiple of the shard count — a padded
+    slot reads the trash page), the page pools replicated. A Mosaic kernel
+    cannot be partitioned by XLA, so a mesh-sharded engine needs this."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.runtime import butterfly_sharding as bsh
+    B = q.shape[0]
+    pad = -B % bsh.shard_count(mesh, axes)
+    if pad:
+        q = jnp.pad(q, ((0, pad),) + ((0, 0),) * (q.ndim - 1))
+        page_table = jnp.pad(page_table, ((0, pad), (0, 0)),
+                             constant_values=TRASH_PAGE)
+        cur_pos = jnp.pad(cur_pos, (0, pad))
+    out = bsh.shard_map_compat(
+        functools.partial(_paged_decode_pallas, interpret=interpret),
+        mesh=mesh, in_specs=(P(axes), P(), P(), P(axes), P(axes)),
+        out_specs=P(axes))(q, k_pool, v_pool, page_table, cur_pos)
+    return out[:B]
+
+
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, page_table: jnp.ndarray,
                            cur_pos: jnp.ndarray, *,
@@ -178,18 +201,28 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 
     ``backend=None`` resolves from the ambient
     :class:`~repro.kernels.context.ExecutionContext` (jnp oracle on CPU,
-    Pallas on TPU, ``pallas_interpret`` under the test contexts).
+    Pallas on TPU, ``pallas_interpret`` under the test contexts). Under an
+    ambient context with a mesh the kernel runs slot-sharded over its data
+    axes (:func:`_sharded_paged_decode`).
     """
+    from repro.kernels import context as exctx
     from repro.obs.profiling import annotate
+    ctx = exctx.current_execution()
     if backend is None:
-        from repro.kernels import context as exctx
-        ctx = exctx.current_execution()
         backend = exctx.resolve_backend(ctx.backend if ctx else "auto")
     with annotate("paged_attention"):
         if backend == "jnp":
             out = paged_attend_ref(q[:, None], k_pool, v_pool, page_table,
                                    cur_pos[:, None])
             return out[:, 0]
-        return _paged_decode_pallas(q, k_pool, v_pool, page_table,
-                                    jnp.asarray(cur_pos, jnp.int32),
-                                    interpret=(backend == "pallas_interpret"))
+        cur_pos = jnp.asarray(cur_pos, jnp.int32)
+        interpret = backend == "pallas_interpret"
+        if ctx is not None and ctx.mesh is not None:
+            from repro.runtime import butterfly_sharding as bsh
+            axes = bsh.data_axes(ctx.mesh, ctx.mesh_axes)
+            if axes:
+                return _sharded_paged_decode(
+                    q, k_pool, v_pool, page_table, cur_pos, mesh=ctx.mesh,
+                    axes=axes, interpret=interpret)
+        return _paged_decode_pallas(q, k_pool, v_pool, page_table, cur_pos,
+                                    interpret=interpret)

@@ -45,91 +45,101 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.butterfly import num_stages
 from repro.kernels import tuning
-from repro.kernels.butterfly import (_butterfly_bwd_block, _flatten_batch,
-                                     _stage_apply)
+from repro.kernels.butterfly import (_apply_stages, _butterfly_bwd_block,
+                                     _chunk, _cols, _copy, _flatten_batch,
+                                     _for_chunks, _padded_rows,
+                                     _zero_on_first_step, checkpoint_scratch,
+                                     tile_scratch, weight_grads, weight_rows)
 
 __all__ = ["sandwich_matmul", "one_hot_select"]
 
 
-def _sandwich_forward_block(x, w_in_ref, sel_in_ref, core_ref, sel_out_ref,
-                            *, stages_in: int, scale_in: float,
-                            scale_out: float):
-    """Shared forward math up to the scatter output ``z`` (pre out-butterfly).
+def _dot(a, b, contract=(1, 0)):
+    """float32 MXU product contracting ``a``'s axis ``contract[0]`` with
+    ``b``'s axis ``contract[1]`` (transposes ride the matmul, not a copy)."""
+    return jax.lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32),
+        (((contract[0],), (contract[1],)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    Returns ``(h1, z)``; ``h1`` is needed by the core gradient in backward.
+
+def _sum_chunks(shape, part, init):
+    """``init + Σ part(cols)`` over the chunks of a ``(rows, n)`` tile."""
+    width = _chunk(shape)
+    return jax.lax.fori_loop(0, shape[-1] // width,
+                             lambda c, acc: acc + part(_cols(c, width)),
+                             init)
+
+
+def _sandwich_forward_block(buf_in, buf_out, w_in_ref, sel_in_ref, core_ref,
+                            sel_out_ref, *, stages_in: int, scale_in: float,
+                            scale_out: float):
+    """Shared forward math up to the scatter output ``z`` (pre
+    out-butterfly), in float32 VMEM buffers like the butterfly stages
+    (:mod:`repro.kernels.butterfly`): ``buf_in`` holds ``x`` and is run
+    through the input butterfly in place; ``buf_out`` receives ``z``.
+
+    Returns ``h1``, which the core gradient needs in backward.
     """
-    for s in range(stages_in):
-        x = _stage_apply(x, w_in_ref[s, 0, :], w_in_ref[s, 1, :], 1 << s,
-                         transpose=False)
-    h1 = jnp.dot(x, sel_in_ref[...],
-                 preferred_element_type=jnp.float32)      # (bb, k1)
-    h1 = h1 * scale_in
-    h2 = jnp.dot(h1, core_ref[...].T.astype(h1.dtype),
-                 preferred_element_type=jnp.float32)      # (bb, k2)
-    z = jnp.dot(h2, sel_out_ref[...].astype(h2.dtype),
-                preferred_element_type=jnp.float32)       # (bb, n2)
-    z = z * scale_out
-    return h1, z
+    _apply_stages(buf_in, w_in_ref, 0, stages_in, stages_in, False)
+    h1 = _sum_chunks(buf_in.shape, lambda cols: _dot(buf_in[:, cols],
+                                           sel_in_ref[cols, :]),
+                     jnp.zeros((buf_in.shape[0], sel_in_ref.shape[1]),
+                               jnp.float32))
+    h2 = _dot(h1 * scale_in, core_ref[...], (1, 1))        # (bb, k2)
+
+    def scatter(cols):
+        buf_out[:, cols] = _dot(h2, sel_out_ref[:, cols]) * scale_out
+
+    _for_chunks(buf_out.shape, scatter)
+    return h1 * scale_in                                    # (bb, k1)
 
 
 def _sandwich_kernel(x_ref, w_in_ref, sel_in_ref, core_ref, sel_out_ref,
-                     w_out_ref, o_ref, *, stages_in: int, stages_out: int,
-                     scale_in: float, scale_out: float):
-    x = x_ref[...]
-    _, z = _sandwich_forward_block(x, w_in_ref, sel_in_ref, core_ref,
-                                   sel_out_ref, stages_in=stages_in,
-                                   scale_in=scale_in, scale_out=scale_out)
-    z = z.astype(x.dtype)
-    for s in reversed(range(stages_out)):
-        z = _stage_apply(z, w_out_ref[s, 0, :], w_out_ref[s, 1, :], 1 << s,
-                         transpose=True)
-    o_ref[...] = z
+                     w_out_ref, o_ref, buf_in, buf_out, *, stages_in: int,
+                     stages_out: int, scale_in: float, scale_out: float):
+    _copy(buf_in, x_ref)
+    _sandwich_forward_block(buf_in, buf_out, w_in_ref, sel_in_ref, core_ref,
+                            sel_out_ref, stages_in=stages_in,
+                            scale_in=scale_in, scale_out=scale_out)
+    _apply_stages(buf_out, w_out_ref, 0, stages_out, stages_out, True)
+    _copy(o_ref, buf_out)
 
 
 def _sandwich_bwd_kernel(x_ref, w_in_ref, sel_in_ref, core_ref, sel_out_ref,
                          w_out_ref, g_ref, dx_ref, dwin_ref, dcore_ref,
-                         dwout_ref, ckpt_out_ref, ckpt_in_ref, *,
-                         stages_in: int, stages_out: int, seg_in: int,
-                         seg_out: int, scale_in: float, scale_out: float):
-    x = x_ref[...]
-    g = g_ref[...]
+                         dwout_ref, buf_in, gbuf_in, buf_out, gbuf_out,
+                         ckpt_out_ref, acts_out_ref, ckpt_in_ref,
+                         acts_in_ref, *, stages_in: int, stages_out: int,
+                         seg_in: int, seg_out: int, scale_in: float,
+                         scale_out: float):
+    _zero_on_first_step(dwin_ref, dcore_ref, dwout_ref)
     # --- recompute forward intermediates (VMEM-resident, no stash) ---
-    h1, z = _sandwich_forward_block(x, w_in_ref, sel_in_ref, core_ref,
-                                    sel_out_ref, stages_in=stages_in,
-                                    scale_in=scale_in, scale_out=scale_out)
-    z = z.astype(x.dtype)
-    # --- VJP through the output (transposed) butterfly ---
-    gz, dwout = _butterfly_bwd_block(z, w_out_ref, g, stages_out,
-                                     transpose=True, segment=seg_out,
-                                     ckpt_ref=ckpt_out_ref)
+    _copy(buf_in, x_ref)
+    h1 = _sandwich_forward_block(buf_in, buf_out, w_in_ref, sel_in_ref,
+                                 core_ref, sel_out_ref, stages_in=stages_in,
+                                 scale_in=scale_in, scale_out=scale_out)
+    # --- VJP through the output (transposed) butterfly: gbuf_out -> dz ---
+    _copy(gbuf_out, g_ref)
+    _butterfly_bwd_block(buf_out, gbuf_out, w_out_ref, stages_out, True,
+                         seg_out, ckpt_out_ref, acts_out_ref, dwout_ref)
     # --- scatter / core / selection chain (float32 on the MXU) ---
-    gzf = gz.astype(jnp.float32) * scale_out
-    dh2 = jnp.dot(gzf, sel_out_ref[...].astype(jnp.float32).T,
-                  preferred_element_type=jnp.float32)     # (bb, k2)
-    dcore = jnp.dot(dh2.T, h1,
-                    preferred_element_type=jnp.float32)   # (k2, k1)
-    dh1 = jnp.dot(dh2, core_ref[...].astype(jnp.float32),
-                  preferred_element_type=jnp.float32)     # (bb, k1)
-    du = jnp.dot(dh1 * scale_in, sel_in_ref[...].astype(jnp.float32).T,
-                 preferred_element_type=jnp.float32)      # (bb, n1)
-    du = du.astype(x.dtype)
-    # --- VJP through the input butterfly ---
-    dx, dwin = _butterfly_bwd_block(x, w_in_ref, du, stages_in,
-                                    transpose=False, segment=seg_in,
-                                    ckpt_ref=ckpt_in_ref)
-    dx_ref[...] = dx.astype(dx_ref.dtype)
+    dh2 = _sum_chunks(gbuf_out.shape, lambda cols: _dot(gbuf_out[:, cols],
+                                            sel_out_ref[:, cols], (1, 1)),
+                      jnp.zeros((x_ref.shape[0], sel_out_ref.shape[0]),
+                                jnp.float32)) * scale_out   # (bb, k2)
+    dcore_ref[...] += _dot(dh2, h1, (0, 0))                  # (k2, k1)
+    dh1 = _dot(dh2, core_ref[...]) * scale_in                # (bb, k1)
 
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        dwin_ref[...] = dwin
-        dcore_ref[...] = dcore
-        dwout_ref[...] = dwout
+    def scatter_back(cols):
+        gbuf_in[:, cols] = _dot(dh1, sel_in_ref[cols, :], (1, 1))
 
-    @pl.when(pl.program_id(0) > 0)
-    def _():
-        dwin_ref[...] += dwin
-        dcore_ref[...] += dcore
-        dwout_ref[...] += dwout
+    _for_chunks(gbuf_in.shape, scatter_back)
+    # --- VJP through the input butterfly: gbuf_in -> dx ---
+    _copy(buf_in, x_ref)
+    _butterfly_bwd_block(buf_in, gbuf_in, w_in_ref, stages_in, False,
+                         seg_in, ckpt_in_ref, acts_in_ref, dwin_ref)
+    _copy(dx_ref, gbuf_in)
 
 
 @functools.lru_cache(maxsize=None)
@@ -160,11 +170,11 @@ def one_hot_select(idx, n: int, dtype=jnp.float32) -> jnp.ndarray:
 def _sandwich_specs(bb, n1, n2, p1, p2, k1, k2):
     return [
         pl.BlockSpec((bb, n1), lambda i: (i, 0)),
-        pl.BlockSpec((p1, 2, n1), lambda i: (0, 0, 0)),
+        pl.BlockSpec((_padded_rows(p1), n1), lambda i: (0, 0)),
         pl.BlockSpec((n1, k1), lambda i: (0, 0)),
         pl.BlockSpec((k2, k1), lambda i: (0, 0)),
         pl.BlockSpec((k2, n2), lambda i: (0, 0)),
-        pl.BlockSpec((p2, 2, n2), lambda i: (0, 0, 0)),
+        pl.BlockSpec((_padded_rows(p2), n2), lambda i: (0, 0)),
     ]
 
 
@@ -187,9 +197,12 @@ def _sandwich_fwd_call(x, b_in, sel_in, core, sel_out, b_out, scale_in,
         in_specs=_sandwich_specs(bb, n1, n2, p1, p2, k1, k2),
         out_specs=pl.BlockSpec((bb, n2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded_b, n2), x.dtype),
+        scratch_shapes=[tile_scratch(bb, n1), tile_scratch(bb, n2)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tuning.vmem_limit_bytes()),
         interpret=interpret,
-    )(x2, b_in.astype(x.dtype), sel_in.astype(x.dtype), core,
-      sel_out, b_out.astype(x.dtype))
+    )(x2, weight_rows(b_in), sel_in.astype(x.dtype), core,
+      sel_out, weight_rows(b_out))
     return out[:b].reshape(*lead, n2)
 
 
@@ -212,8 +225,6 @@ def _sandwich_bwd_call(x, b_in, sel_in, core, sel_out, b_out, g, scale_in,
     grid = (padded_b // bb,)
     in_specs = _sandwich_specs(bb, n1, n2, p1, p2, k1, k2)
     in_specs.append(pl.BlockSpec((bb, n2), lambda i: (i, 0)))
-    n_ckpt_in = len(range(0, stages_in, seg_in))
-    n_ckpt_out = len(range(0, stages_out, seg_out))
     dx, dwin, dcore, dwout = pl.pallas_call(
         functools.partial(_sandwich_bwd_kernel, stages_in=stages_in,
                           stages_out=stages_out, seg_in=seg_in,
@@ -223,24 +234,27 @@ def _sandwich_bwd_call(x, b_in, sel_in, core, sel_out, b_out, g, scale_in,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((bb, n1), lambda i: (i, 0)),
-            pl.BlockSpec((p1, 2, n1), lambda i: (0, 0, 0)),
+            pl.BlockSpec((_padded_rows(p1), n1), lambda i: (0, 0)),
             pl.BlockSpec((k2, k1), lambda i: (0, 0)),
-            pl.BlockSpec((p2, 2, n2), lambda i: (0, 0, 0)),
+            pl.BlockSpec((_padded_rows(p2), n2), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((padded_b, n1), x.dtype),
-            jax.ShapeDtypeStruct((p1, 2, n1), jnp.float32),
+            jax.ShapeDtypeStruct((_padded_rows(p1), n1), jnp.float32),
             jax.ShapeDtypeStruct((k2, k1), jnp.float32),
-            jax.ShapeDtypeStruct((p2, 2, n2), jnp.float32),
+            jax.ShapeDtypeStruct((_padded_rows(p2), n2), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((n_ckpt_out, bb, n2), x2.dtype),
-            pltpu.VMEM((n_ckpt_in, bb, n1), x2.dtype),
-        ],
+        scratch_shapes=([tile_scratch(bb, n1), tile_scratch(bb, n1),
+                         tile_scratch(bb, n2), tile_scratch(bb, n2)]
+                        + checkpoint_scratch(stages_out, seg_out, bb, n2)
+                        + checkpoint_scratch(stages_in, seg_in, bb, n1)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tuning.vmem_limit_bytes()),
         interpret=interpret,
-    )(x2, b_in.astype(x.dtype), sel_in.astype(x.dtype), core,
-      sel_out, b_out.astype(x.dtype), g2)
-    return dx[:b].reshape(*lead, n1), dwin, dcore, dwout
+    )(x2, weight_rows(b_in), sel_in.astype(x.dtype), core,
+      sel_out, weight_rows(b_out), g2)
+    return (dx[:b].reshape(*lead, n1), weight_grads(dwin, p1), dcore,
+            weight_grads(dwout, p2))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))

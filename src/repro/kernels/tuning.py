@@ -11,14 +11,14 @@ backward keeps ~2·⌈√p⌉ activation tiles live, so a 256-row tile would nee
 This module picks ``block_b`` (batch-tile rows) and ``segment`` (backward
 checkpoint segment length, see :mod:`repro.kernels.butterfly`) per
 ``(kernel, n, dtype, direction)`` from an analytic VMEM-footprint model plus
-the roofline constants of :mod:`repro.launch.roofline`:
+the chip's row of :data:`repro.launch.roofline.DEVICE_SPECS`:
 
-* footprint model — weights + weight-grad accumulators + the number of
-  activation tiles the kernel keeps live (2 forward; ``⌈p/seg⌉ + seg + 3``
-  for the checkpointed backward) must fit the VMEM budget;
-* roofline estimate — per-row FLOPs over ``PEAK_FLOPS`` vs per-row HBM bytes
-  over ``HBM_BW``; reported in :class:`KernelChoice` so benchmarks and the
-  trainer can record *why* a block size was picked.
+* footprint model — double-buffered input/output tiles and float32 weights,
+  plus the float32 working tiles (1 forward; 2 + ``⌈p/seg⌉ + seg``
+  checkpoint/recompute tiles backward) must fit the VMEM budget;
+* roofline estimate — per-row FLOPs over peak FLOP/s vs per-row HBM bytes
+  over HBM bandwidth; reported in :class:`KernelChoice` so benchmarks and
+  the trainer can record *why* a block size was picked.
 
 Choices are cached (``functools.lru_cache``) and overridable. The override
 order matches :mod:`repro.kernels.context`: an explicit value (from an
@@ -31,8 +31,9 @@ ambient context, which beats the env vars, which beat the model:
   (``ExecutionContext.segment`` beats it).
 * ``REPRO_TUNE_BLOCK_Q``   — force the flash-attention q/kv block size
   (ambient ``ExecutionContext.flash_block_q`` beats it).
-* ``REPRO_TUNE_VMEM_BUDGET`` — VMEM budget in bytes (default: 75% of 16 MB;
-  ambient ``ExecutionContext.vmem_budget`` beats it).
+* ``REPRO_TUNE_VMEM_BUDGET`` — VMEM budget in bytes (default: 75% of the
+  compiler's default scoped-VMEM limit, 12 of 16 MiB on v5e; ambient
+  ``ExecutionContext.vmem_budget`` beats it).
 
 Callers never pass magic numbers: an unset knob anywhere in
 :mod:`repro.kernels.ops`, :mod:`repro.core.layers`, :mod:`repro.core.encdec`
@@ -47,11 +48,12 @@ import os
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.butterfly import num_stages
 from repro.kernels import context as exctx
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS, VMEM_BYTES
+from repro.launch import roofline
 
 __all__ = [
     "KernelChoice",
@@ -61,14 +63,21 @@ __all__ = [
     "default_segment",
     "flash_blocks",
     "vmem_budget",
+    "vmem_limit_bytes",
+    "device_spec",
     "cache_entries",
     "describe",
 ]
 
-# v5e VMEM per core is ~16 MB (roofline.VMEM_BYTES); Mosaic needs headroom
-# for its own double-buffering and spills, so the model budgets a fraction
-# of it. The model only has to be right to within a power of two — block_b
-# candidates are powers of two anyway.
+# The footprint model budgets VMEM_FRACTION of the compiler's default
+# scoped-VMEM limit (16 MiB on v5e): when XLA fuses a kernel call with the
+# ops feeding it (inside the layer scan it does), that default is the limit
+# that holds, whatever the kernel asked for. Kernels whose sublane-floor
+# tile cannot fit it (the 65536-wide LM head) run unfused under the limit
+# they request, VMEM_LIMIT_FRACTION of the chip's VMEM. The model only has
+# to be right to within a power of two — block_b candidates are powers of
+# two anyway.
+VMEM_LIMIT_FRACTION = 0.75
 VMEM_FRACTION = 0.75
 
 MIN_BLOCK_B = 8
@@ -95,12 +104,26 @@ class KernelChoice:
                 f"roofline={self.est_us_per_row:.4f}us/row")
 
 
+def device_spec() -> roofline.DeviceSpec:
+    """The chip the kernels are sized for: the attached TPU's row of
+    :data:`repro.launch.roofline.DEVICE_SPECS` (a TPU missing from it
+    raises), or v5e — the target the CPU and interpret paths model."""
+    if jax.default_backend() == "tpu":
+        return roofline.device_spec(jax.devices()[0].device_kind)
+    return roofline.V5E
+
+
+def vmem_limit_bytes() -> int:
+    """Scoped VMEM the fused kernels request from Mosaic."""
+    return int(device_spec().vmem_bytes * VMEM_LIMIT_FRACTION)
+
+
 def vmem_budget() -> int:
     """VMEM bytes the footprint model may spend.
 
     Override order: ambient :class:`~repro.kernels.context.ExecutionContext`
-    (``vmem_budget`` field), then ``REPRO_TUNE_VMEM_BUDGET``, then 75% of
-    the roofline VMEM constant.
+    (``vmem_budget`` field), then ``REPRO_TUNE_VMEM_BUDGET``, then
+    ``VMEM_FRACTION`` of the chip's default scoped-VMEM limit.
     """
     ctx = exctx.current_execution()
     if ctx is not None and ctx.vmem_budget is not None:
@@ -108,7 +131,7 @@ def vmem_budget() -> int:
     env = os.environ.get("REPRO_TUNE_VMEM_BUDGET", "").strip()
     if env:
         return int(env)
-    return int(VMEM_BYTES * VMEM_FRACTION)
+    return int(device_spec().scoped_vmem_bytes * VMEM_FRACTION)
 
 
 def default_segment(stages: int) -> int:
@@ -137,28 +160,39 @@ def _live_tiles(stages: int, segment: int, mode: str) -> int:
     return n_ckpt + min(segment, stages) + 3
 
 
+def _weight_bytes(n: int, stages: int) -> int:
+    """One butterfly's stage weights as the kernels hold them: float32 rows,
+    padded to whole 8-row tiles (:func:`repro.kernels.butterfly.weight_rows`)."""
+    return -(-2 * stages // 8) * 8 * n * 4
+
+
 def _footprint(kernel: str, n: int, dtype_name: str, stages: int,
                block_b: int, segment: int, mode: str) -> int:
-    """Modeled peak VMEM bytes for one grid step."""
-    item = _itemsize(dtype_name)
-    w_bytes = 2 * stages * n * item
-    tile = block_b * n * item
-    total = w_bytes + _live_tiles(stages, segment, mode) * tile
-    if mode == "bwd":
-        total += 2 * stages * n * 4                # float32 dw accumulator
+    """Modeled peak VMEM bytes for one grid step.
+
+    Input/output tiles and weights are double-buffered by the pipeline, as
+    are the backward's float32 weight-gradient outputs. The stages update
+    a float32 working tile in place (the backward adds the cotangent's tile
+    and ``⌈p/seg⌉ + seg`` checkpoint/recompute tiles); traced values are
+    one chunk wide and not counted.
+    """
+    tile_io = block_b * n * _itemsize(dtype_name)
+    tile32 = block_b * n * 4
+    w = _weight_bytes(n, stages)
+    if mode == "fwd":
+        io, weights, work = 4 * tile_io, 2 * w, tile32
+    else:
+        n_ckpt = -(-stages // segment)
+        io, weights = 6 * tile_io, 4 * w
+        work = (2 + n_ckpt + min(segment, stages)) * tile32
+    total = io + weights + work
     if kernel == "sandwich":
-        # second butterfly's weights (+ grads) and the small core/selection
-        # matrices; modeled at the same n (the tuner is called with
-        # max(n1, n2), conservative for the smaller side)
-        total += w_bytes + (2 * stages * n * 4 if mode == "bwd" else 0)
-        if mode == "bwd":
-            # the sandwich backward allocates a checkpoint scratch buffer
-            # *per butterfly* and runs a second within-segment recompute,
-            # so its butterfly-specific live tiles (everything beyond the
-            # shared x/g/dx) are paid twice
-            total += (_live_tiles(stages, segment, mode) - 3) * tile
-        k = max(2, stages)                          # paper's k = log2 n
-        total += 2 * n * k * item + k * k * item
+        # the second butterfly's weights, gradients and working tiles,
+        # modeled at the same n — the tuner is called with max(n1, n2),
+        # conservative for the smaller side — plus the selections and core
+        # (paper's k = log2 n), double-buffered
+        k = max(2, stages)
+        total += weights + work + 2 * (2 * n * k + k * k) * 4
     return total
 
 
@@ -173,7 +207,8 @@ def _roofline_us_per_row(kernel: str, n: int, dtype_name: str,
     if kernel == "sandwich":
         flops *= 2.0
     hbm = 2.0 * n * item * (2.0 if mode == "bwd" else 1.0)
-    return max(flops / PEAK_FLOPS, hbm / HBM_BW) * 1e6
+    spec = device_spec()
+    return max(flops / spec.peak_flops, hbm / spec.hbm_bw) * 1e6
 
 
 @functools.lru_cache(maxsize=None)

@@ -224,7 +224,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         print(f"[{arch} × {shape_name} × {mesh_name}] compiled in "
               f"{compile_s:.1f}s")
         print(f"  memory_analysis: {ma}")
-        ca = rl.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         print(f"  cost: flops/dev={ca.get('flops', 0):.3e} "
               f"bytes/dev={ca.get('bytes accessed', 0):.3e}")
         print(f"  roofline: compute={report.t_compute*1e3:.2f}ms "

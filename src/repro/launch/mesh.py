@@ -12,21 +12,13 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto on every axis
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types when the jax version has them
-    (older ``make_mesh`` signatures take no ``axis_types`` at all)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -3,9 +3,9 @@
 Per (arch × shape × mesh) cell the dry-run produces a compiled executable;
 this module derives the three roofline terms (seconds, per device):
 
-    compute    = per_device_HLO_FLOPs / PEAK_FLOPS
-    memory     = per_device_HLO_bytes / HBM_BW
-    collective = per_device_collective_bytes / ICI_BW
+    compute    = per_device_HLO_FLOPs / peak FLOP/s
+    memory     = per_device_HLO_bytes / HBM bandwidth
+    collective = per_device_collective_bytes / ICI bandwidth per link
                  (+ DCN-crossing collectives on the `pod` axis at DCN_BW,
                   reported separately and included in the term)
 
@@ -15,8 +15,10 @@ compiled HLO text: we sum output-shape bytes of every all-reduce /
 all-gather / reduce-scatter / all-to-all / collective-permute op, classified
 by whether the replica group spans the ``pod`` axis.
 
-Hardware constants (TPU v5e per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI; DCN between pods is modeled at 25 GB/s/host-link.
+Hardware constants live in one table, :data:`DEVICE_SPECS`, keyed by the
+``device_kind`` JAX reports; :func:`device_spec` refuses a kind that is not
+in it. The dry-run models a v5e pod (:data:`V5E`); DCN between pods is
+modeled at 25 GB/s/host-link.
 """
 
 from __future__ import annotations
@@ -25,12 +27,48 @@ import re
 from dataclasses import asdict, dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclass(frozen=True)
+class DeviceSpec:
+    """Per-chip peaks and on-chip memory of one TPU generation."""
+
+    kind: str                  # jax.devices()[i].device_kind
+    peak_flops: float          # bf16 FLOP/s
+    hbm_bw: float              # HBM bytes/s
+    hbm_bytes: int             # HBM capacity
+    ici_bw: float              # interconnect bytes/s per link
+    vmem_bytes: int            # VMEM per TensorCore
+    scoped_vmem_bytes: int     # compiler's default scoped-VMEM limit
+    source: str
+
+
+DEVICE_SPECS: Dict[str, DeviceSpec] = {
+    "TPU v5 lite": DeviceSpec(
+        kind="TPU v5 lite", peak_flops=197e12, hbm_bw=819e9,
+        hbm_bytes=16 * 10 ** 9,
+        ici_bw=1600e9 / 8 / 4,     # 1,600 Gbit/s per chip over 4 links
+        vmem_bytes=128 * 2 ** 20, scoped_vmem_bytes=16 * 2 ** 20,
+        source=("Google Cloud documentation, 'TPU v5e' (peak bf16, HBM "
+                "capacity and bandwidth, interconnect); VMEM from the chip "
+                "table of jax._src.pallas.mosaic.tpu_info (jax 0.9.0); "
+                "scoped-VMEM default from the TPU compiler's limit "
+                "(libtpu 0.0.34)")),
+}
+
+V5E = DEVICE_SPECS["TPU v5 lite"]
 DCN_BW = 25e9              # bytes/s per pod uplink (modeled)
-VMEM_BYTES = 16 * 2 ** 20  # VMEM per core — the kernel autotuner's budget
-                           # base (repro.kernels.tuning)
+
+
+def device_spec(kind: str) -> DeviceSpec:
+    """The table row for one ``device_kind``; an unknown TPU is an error,
+    never a default."""
+    try:
+        return DEVICE_SPECS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak/VMEM entry for device_kind {kind!r}; add a row with "
+            f"its source to repro.launch.roofline.DEVICE_SPECS "
+            f"(known: {sorted(DEVICE_SPECS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -145,15 +183,15 @@ class RooflineReport:
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / V5E.peak_flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / V5E.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return (self.collective.ici_bytes / ICI_BW
+        return (self.collective.ici_bytes / V5E.ici_bw
                 + self.collective.dcn_bytes / DCN_BW)
 
     @property
@@ -170,7 +208,7 @@ class RooflineReport:
     def roofline_fraction(self) -> float:
         """Fraction of the step spent on *useful* compute if perfectly
         overlapped: model_flops_time / max(term)."""
-        t_model = self.model_flops / PEAK_FLOPS
+        t_model = self.model_flops / V5E.peak_flops
         b = self.bound_time
         return t_model / b if b > 0 else 0.0
 
@@ -185,7 +223,7 @@ class RooflineReport:
     def hbm_fit(self) -> bool:
         per_dev = (self.argument_bytes + self.output_bytes
                    + self.temp_bytes - self.alias_bytes)
-        return per_dev <= 16e9    # v5e: 16 GB HBM
+        return per_dev <= V5E.hbm_bytes
 
     def to_dict(self) -> Dict:
         d = {
@@ -213,15 +251,6 @@ class RooflineReport:
             "hbm_fit": self.hbm_fit,
         }
         return d
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: newer jax returns a
-    flat dict, jax <= 0.4.x a one-element list of dicts."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
 
 
 def build_report(arch: str, shape: str, mesh_name: str, n_devices: int,

@@ -13,7 +13,10 @@ plus the JSON metrics snapshot (per-engine, or the router's aggregate
 with per-replica detail). ``--checkpoint-dir`` restores the newest valid
 :mod:`repro.checkpoint` checkpoint (fresh init otherwise);
 ``--mesh-shape 8`` serves over an 8-device ``("data",)`` mesh —
-``--simulated-devices 8`` simulates one on CPU.
+``--simulated-devices 8`` simulates one on CPU. Without a mesh, replica
+``i`` keeps its weights and page pool on device ``i`` (wrapping round when
+there are more replicas than devices): four replicas on a four-chip host
+use all four chips.
 
 Robustness knobs: ``--admission incremental`` switches to prompt-only page
 reservation with preempt-youngest/recompute (vLLM's policy);
@@ -34,6 +37,7 @@ import json
 import os
 import sys
 import threading
+from typing import Callable, List
 
 # Simulated multi-device serving: the host device count must reach XLA
 # before jax initializes (jax-free helper shared with launch/train.py).
@@ -41,7 +45,33 @@ from repro.launch._prejax import apply_simulated_devices
 
 apply_simulated_devices(sys.argv)
 
+import jax  # noqa: E402
 import numpy as np  # noqa: E402
+
+
+def build_replicas(n: int, cfg, params, make_engine: Callable,
+                   context=None) -> List:
+    """``n`` engines from ``make_engine(i, params, context)``. Replica
+    ``i``'s weights are committed to ``jax.devices()[i % count]`` and its
+    engine (page pool included) is built there, unless the execution
+    context (``context`` over ``cfg``'s butterfly config) has a mesh: such
+    an engine spans the devices itself and is built unplaced."""
+    from repro.kernels import context as exctx
+
+    spans = exctx.resolve_execution(
+        context, default=exctx.ExecutionContext.from_butterfly_config(
+            cfg.butterfly)).mesh is not None
+    devices = jax.devices()
+    engines = []
+    for i in range(n):
+        if spans:
+            engines.append(make_engine(i, params, context))
+            continue
+        dev = devices[i % len(devices)]
+        with jax.default_device(dev):
+            engines.append(make_engine(i, jax.device_put(params, dev),
+                                       context))
+    return engines
 
 
 def _write_json_atomic(path: str, doc) -> None:
@@ -170,18 +200,21 @@ def main():
     # per-replica families apart via the {"replica": i} label
     obs_registry = MetricsRegistry()
     tracer = Tracer() if args.trace_out else NULL_TRACER
-    engines = [ServeEngine(
-        cfg, params, slots=args.slots, max_len=args.max_len,
-        pool=args.pool, page_size=args.page_size,
-        num_pages=args.num_pages or None,
-        prefill_chunk=args.prefill_chunk or None,
-        sampling=SamplingParams(temperature=args.temperature,
-                                top_k=args.top_k, top_p=args.top_p),
-        admission=args.admission, spec_k=args.spec_k,
-        queue_limit=args.queue_limit or None,
-        faults=faults, context=context, seed=args.seed,
-        tracer=tracer, registry=obs_registry, replica=i)
-        for i, faults in enumerate(injectors)]
+
+    def make_engine(i, p, ctx):
+        return ServeEngine(
+            cfg, p, slots=args.slots, max_len=args.max_len,
+            pool=args.pool, page_size=args.page_size,
+            num_pages=args.num_pages or None,
+            prefill_chunk=args.prefill_chunk or None,
+            sampling=SamplingParams(temperature=args.temperature,
+                                    top_k=args.top_k, top_p=args.top_p),
+            admission=args.admission, spec_k=args.spec_k,
+            queue_limit=args.queue_limit or None,
+            faults=injectors[i], context=ctx, seed=args.seed,
+            tracer=tracer, registry=obs_registry, replica=i)
+
+    engines = build_replicas(args.replicas, cfg, params, make_engine, context)
     engine, faults = engines[0], injectors[0]
     print(f"[serve] {cfg.name} | params: {src} | slots={args.slots} "
           f"max_len={args.max_len} pool={engine.pool.kind} "
@@ -324,4 +357,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

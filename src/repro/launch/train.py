@@ -9,24 +9,26 @@ same Trainer/step code shards across it (the dry-run proves the production
 mesh compiles for every assigned config). On CPU it trains the smoke
 variants end-to-end.
 
-Compute/communication overlap: we enable XLA's latency-hiding scheduler and
-async collectives by default (effective on TPU; harmless on CPU).
+Compute/communication overlap: ``--xla-perf-flags`` turns on the TPU
+compiler's latency-hiding scheduler and async collectives.
 """
 
 import argparse
 import os
 
-_XLA_PERF_FLAGS = (
+_TPU_PERF_FLAGS = (
     "--xla_tpu_enable_latency_hiding_scheduler=true "
     "--xla_tpu_megacore_fusion_allow_ags=true "
     "--xla_enable_async_collective_permute=true "
     "--xla_tpu_enable_async_collective_fusion=true"
 )
-# TPU-only flags (the CPU runtime rejects them): enabled with
-# --xla-perf-flags on real hardware.
+# libtpu reads its compiler flags from LIBTPU_INIT_ARGS (the CPU runtime
+# rejects them in XLA_FLAGS); they are appended to whatever the variable
+# already holds, never replacing it.
 if "--xla-perf-flags" in os.sys.argv:
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
-                               + _XLA_PERF_FLAGS).strip()
+    os.environ["LIBTPU_INIT_ARGS"] = (
+        os.environ.get("LIBTPU_INIT_ARGS", "") + " " + _TPU_PERF_FLAGS
+    ).strip()
 
 # Simulated multi-device CPU run (--simulated-devices N): the host device
 # count must reach XLA before jax initializes, hence the pre-import peek
@@ -120,4 +122,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -20,8 +20,8 @@ entry points wrapped in ``shard_map`` over the data-parallel mesh axes
 
 The psum lives in an explicit outer :func:`jax.custom_vjp` rather than in
 ``shard_map``'s transpose so the replicated-weight gradient semantics never
-depend on per-version replication-checking behavior (``check_rep`` /
-``check_vma``) — the same reason :mod:`repro.runtime.pipeline` disables the
+depend on ``shard_map``'s replication check (``check_vma``) — the same
+reason :mod:`repro.runtime.pipeline` disables the
 check around its ppermute schedule.
 
 Execution policy arrives as a finalized

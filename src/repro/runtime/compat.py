@@ -1,28 +1,20 @@
-"""jax version-compat shims shared across the runtime modules.
+"""``shard_map`` with the replication check off, shared by the runtime.
 
-jax >= 0.6 promotes ``shard_map`` to the top level and renames
-``check_rep`` -> ``check_vma``; older jax keeps it in ``jax.experimental``.
 Both callers (``runtime.pipeline``, ``runtime.butterfly_sharding``) disable
-the replication check on purpose: the pipeline's output psum breaks
-per-shard replication tracking by construction, and the butterfly wrapper
-psums its weight gradients explicitly so their semantics never depend on
-the check's behavior.
+the check on purpose: the pipeline's output psum breaks per-shard
+replication tracking by construction, and the butterfly wrapper psums its
+weight gradients explicitly so their semantics never depend on the check's
+behavior.
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map_compat(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
 
-    def shard_map_compat(f, *, mesh, in_specs, out_specs):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map_compat(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 __all__ = ["shard_map_compat"]

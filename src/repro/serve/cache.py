@@ -403,11 +403,15 @@ class PagedCachePool(CachePool):
         self._owned[slot] = []
         self._table[slot, :] = TRASH_PAGE
 
+    # Both hand jax a private copy of the table: jax may alias a numpy
+    # buffer (the CPU does when it is 64-byte aligned) or read it after
+    # returning (an asynchronous host-to-device copy), and the allocator
+    # rewrites the table (grow, free) while a dispatched step is in flight.
     def gather_args(self) -> Dict[str, jnp.ndarray]:
-        return {"page_table": jnp.asarray(self._table)}
+        return {"page_table": jnp.asarray(self._table.copy())}
 
     def page_row(self, slot: int) -> jnp.ndarray:
-        return jnp.asarray(self._table[slot])
+        return jnp.asarray(self._table[slot].copy())
 
     @property
     def pages_in_use(self) -> int:

@@ -922,6 +922,12 @@ class ServeEngine:
     def active_requests(self) -> List[int]:
         return [s.rid for s in self._slots if s is not None]
 
+    def devices(self) -> set:
+        """Devices holding this engine's weights and cache pool."""
+        return {d for leaf in jax.tree_util.tree_leaves(
+                    (self._params, self._caches))
+                for d in leaf.devices()}
+
     @property
     def compile_stats(self) -> Dict:
         return {"compiles": self.compile_cache.compiles,

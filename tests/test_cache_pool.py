@@ -95,6 +95,26 @@ def test_replay_determinism(cfg):
 # Typed backpressure
 # ---------------------------------------------------------------------------
 
+def test_device_table_snapshots_survive_free(cfg):
+    """Regression: ``page_row``/``gather_args`` handed jax the live numpy
+    table, which jax on the CPU aliases when the buffer is 64-byte aligned;
+    ``free()`` then rewrote a dispatched scrub's page row to trash, and the
+    request's KV survived in its recycled pages. Many pools, so some rows
+    land on an aligned address."""
+    for slots in range(1, 33):
+        pool = _pool(cfg, slots=slots, max_len=16, page_size=4)
+        for s in range(slots):
+            pool.alloc_pages(s, 16)
+        want = pool._table.copy()
+        rows = [pool.page_row(s) for s in range(slots)]
+        table = pool.gather_args()["page_table"]
+        for s in range(slots):
+            pool.free(s)
+        assert (np.asarray(table) == want).all()
+        for s, row in enumerate(rows):
+            assert (np.asarray(row) == want[s]).all(), (slots, s)
+
+
 def test_pool_exhausted_is_typed_and_non_destructive(cfg):
     pool = _pool(cfg, num_pages=4)         # 3 usable
     pool.alloc_pages(0, 16)                # 2 pages

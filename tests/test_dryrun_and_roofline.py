@@ -34,7 +34,7 @@ def test_analyzer_counts_scan_trip_multiplicity():
     xs = jax.ShapeDtypeStruct((12, 64, 64), jnp.float32)
     compiled = jax.jit(f).lower(c, xs).compile()
     # cost_analysis undercounts (counts the body once) ...
-    assert rl.cost_analysis_dict(compiled)["flops"] < 12 * 2 * 64**3 / 2
+    assert compiled.cost_analysis()["flops"] < 12 * 2 * 64**3 / 2
     # ... the loop-aware analyzer does not
     cost = ha.analyze(compiled.as_text())
     np.testing.assert_allclose(cost.flops, 12 * 2 * 64**3, rtol=0.05)

@@ -119,3 +119,30 @@ def test_cli_rejects_bad_geometry(monkeypatch, tmp_path):
                  "--min-prompt", "8")
     with pytest.raises(SystemExit, match="--replicas"):
         _run_cli(monkeypatch, "--arch", ARCH, "--replicas", "0")
+
+
+def test_build_replicas_places_replicas_unless_a_mesh_spans_devices():
+    """Without a mesh, replica i's weights and page pool sit on device i
+    (8 virtual CPU devices); an engine whose context has a mesh is built
+    unplaced, on the caller's weights."""
+    import jax
+
+    from repro.configs import registry
+    from repro.kernels.context import ExecutionContext
+    from repro.launch.serve import build_replicas
+    from repro.serve import ServeEngine, loader
+
+    cfg = registry.get("smollm-135m-butterfly-smoke")
+    params = loader.init_params(cfg, seed=0)
+
+    def make(i, p, ctx):
+        return ServeEngine(cfg, p, slots=2, max_len=32, pool="paged",
+                           replica=i, context=ctx)
+
+    devices = jax.devices()
+    engines = build_replicas(3, cfg, params, make)
+    assert [e.devices() for e in engines] == [{d} for d in devices[:3]]
+    sharded, = build_replicas(1, cfg, params, make,
+                              ExecutionContext(mesh_shape=(4,)))
+    assert sharded.ctx.mesh_layout() == "data=4"
+    assert sharded._params is params

@@ -168,3 +168,26 @@ def test_backend_resolves_from_ambient_context():
         got = pa.paged_decode_attention(q, k_pool, v_pool, ids, cur)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-6, rtol=1e-6)
+
+
+def test_kernel_under_a_mesh_runs_slot_sharded():
+    """Under an ambient context with a data mesh the kernel runs inside
+    ``shard_map`` (XLA cannot partition a Mosaic kernel): slots split over
+    the 8 devices, 2 slots zero-padded to 8 onto the trash page, and the
+    result matches the oracle."""
+    _, _, k_pool, v_pool, ids = _setup(seed=11)
+    rng = np.random.default_rng(12)
+    q = jnp.asarray(rng.normal(size=(B, KV, G, D)), jnp.float32)
+    cur = jnp.asarray([5, 10], jnp.int32)
+    want = pa.paged_decode_attention(q, k_pool, v_pool, ids, cur,
+                                     backend="jnp")
+    ctx = exctx.resolve_execution(exctx.ExecutionContext(
+        backend="pallas_interpret", mesh_shape=(8,)))
+    with exctx.use_execution(ctx):
+        jaxpr = jax.make_jaxpr(pa.paged_decode_attention)(
+            q, k_pool, v_pool, ids, cur)
+        got = jax.jit(pa.paged_decode_attention)(q, k_pool, v_pool, ids,
+                                                 cur)
+    assert "shard_map" in str(jaxpr)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-6)

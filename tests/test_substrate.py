@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint.checkpointing import CheckpointManager
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
