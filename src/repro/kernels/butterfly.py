@@ -403,6 +403,7 @@ def _butterfly_fwd_call(x: jnp.ndarray, w: jnp.ndarray, transpose: bool,
     out = pl.pallas_call(
         functools.partial(_butterfly_kernel, stages=stages,
                           transpose=transpose),
+        name="butterfly_matmul_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bb, n), lambda i: (i, 0)),
@@ -431,6 +432,7 @@ def _butterfly_bwd_call(x: jnp.ndarray, w: jnp.ndarray, g: jnp.ndarray,
     dx, dw = pl.pallas_call(
         functools.partial(_butterfly_bwd_kernel, stages=stages,
                           transpose=transpose, segment=seg),
+        name="butterfly_matmul_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bb, n), lambda i: (i, 0)),

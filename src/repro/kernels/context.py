@@ -145,10 +145,6 @@ class ExecutionContext:
       (``None`` = ``REPRO_TUNE_VMEM_BUDGET`` / ``REPRO_TUNE_BLOCK_Q`` env,
       then the model defaults). Read ambiently by
       :mod:`repro.kernels.tuning`.
-    * ``profile`` — emit ``jax.profiler.TraceAnnotation`` around the fused
-      kernel call sites (:mod:`repro.obs.profiling`) so device profiles
-      line up with the serving tier's span names. ``None`` = unset: falls
-      through to the ``REPRO_PROFILE`` env var, default off.
 
     Hashable and frozen: safe to close over in jit, to key lru caches on,
     and to store on a module (:class:`repro.nn.ButterflyLinear`).
@@ -162,7 +158,6 @@ class ExecutionContext:
     mesh: Optional[Mesh] = None
     vmem_budget: Optional[int] = None
     flash_block_q: Optional[int] = None
-    profile: Optional[bool] = None
 
     def __post_init__(self):
         if self.backend not in ("auto",) + CONCRETE_BACKENDS:
@@ -243,8 +238,6 @@ class ExecutionContext:
             parts.append(f"mesh_shape={self.mesh_shape}")
         if self.mesh_axes is not None:
             parts.append(f"mesh_axes={self.mesh_axes}")
-        if self.profile is not None:
-            parts.append(f"profile={self.profile}")
         return " ".join(parts)
 
 

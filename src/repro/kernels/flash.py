@@ -192,6 +192,7 @@ def _flash_fwd_call(q, k, v, causal, window, block_q, block_kv, interpret,
         functools.partial(_flash_kernel, block_q=block_q,
                           block_kv=block_kv, seq_len=S, causal=causal,
                           window=window, scale=scale),
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -242,6 +243,7 @@ def _flash_bwd_call(q, k, v, out, lse, g, causal, window, block_q, block_kv,
     dq_specs[5] = pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kw),
+        name="flash_attention_bwd_dq",
         grid=(B * H, S // block_q),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -253,6 +255,7 @@ def _flash_bwd_call(q, k, v, out, lse, g, causal, window, block_q, block_kv,
     dkv_specs[2] = pl.BlockSpec((None, block_kv, D), lambda b, i: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **kw),
+        name="flash_attention_bwd_dkv",
         grid=(B * H, S // block_kv),
         in_specs=dkv_specs,
         out_specs=[
@@ -306,12 +309,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     the O(S²) probability matrix. ``block_q``/``block_kv`` default to the
     :mod:`repro.kernels.tuning` VMEM model; pass ints only to override.
     """
-    from repro.obs.profiling import annotate
     B, H, S, D = q.shape
     if block_q is None or block_kv is None:
         bq, bkv = tuning.flash_blocks(S, D, jnp.dtype(q.dtype).name, "bwd")
         block_q = block_q or bq
         block_kv = block_kv or bkv
-    with annotate("flash_attention"):
-        return _flash_diff(q, k, v, causal, window, block_q, block_kv,
-                           interpret)
+    return _flash_diff(q, k, v, causal, window, block_q, block_kv,
+                       interpret)

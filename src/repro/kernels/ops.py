@@ -38,7 +38,6 @@ from repro.kernels.context import (Backend, ExecutionContext,
                                    use_execution)
 from repro.kernels.sandwich import sandwich_matmul as _sandwich_pallas
 from repro.kernels.sandwich import one_hot_select
-from repro.obs.profiling import annotate as _annotate
 
 
 def _sharded_route(ctx: ExecutionContext):
@@ -60,15 +59,13 @@ def _local_butterfly(x: jnp.ndarray, w: jnp.ndarray, *, transpose: bool,
     :mod:`repro.runtime.butterfly_sharding` call this directly so an
     ambient mesh context can never re-route a call that is already inside
     its own shard."""
-    with _annotate("butterfly_matmul", ctx):
-        if ctx.backend == "jnp":
-            return _ref.butterfly_ref(w.astype(x.dtype), x,
-                                      transpose=transpose)
-        with use_execution(ctx):  # tuning overrides (vmem_budget) see ctx
-            return _butterfly_pallas(
-                x, w, transpose=transpose,
-                block_b=ctx.block_b, segment=ctx.segment,
-                interpret=ctx.backend == "pallas_interpret")
+    if ctx.backend == "jnp":
+        return _ref.butterfly_ref(w.astype(x.dtype), x, transpose=transpose)
+    with use_execution(ctx):  # tuning overrides (vmem_budget) see ctx
+        return _butterfly_pallas(
+            x, w, transpose=transpose,
+            block_b=ctx.block_b, segment=ctx.segment,
+            interpret=ctx.backend == "pallas_interpret")
 
 
 def butterfly_apply(x: jnp.ndarray, w: jnp.ndarray, *,
@@ -117,16 +114,15 @@ def _local_sandwich(x, b_in, sel_in, core, sel_out, b_out, *,
                     ctx: ExecutionContext) -> jnp.ndarray:
     """Single-device sandwich dispatch on a finalized context (see
     :func:`_local_butterfly`)."""
-    with _annotate("sandwich_matmul", ctx):
-        if ctx.backend == "jnp":
-            return _ref.sandwich_ref(x, b_in, core, b_out, sel_in, sel_out,
-                                     scale_in, scale_out)
-        with use_execution(ctx):
-            return _sandwich_pallas(
-                x, b_in, sel_in, core, sel_out, b_out,
-                scale_in=scale_in, scale_out=scale_out,
-                block_b=ctx.block_b, segment=ctx.segment,
-                interpret=ctx.backend == "pallas_interpret")
+    if ctx.backend == "jnp":
+        return _ref.sandwich_ref(x, b_in, core, b_out, sel_in, sel_out,
+                                 scale_in, scale_out)
+    with use_execution(ctx):
+        return _sandwich_pallas(
+            x, b_in, sel_in, core, sel_out, b_out,
+            scale_in=scale_in, scale_out=scale_out,
+            block_b=ctx.block_b, segment=ctx.segment,
+            interpret=ctx.backend == "pallas_interpret")
 
 
 __all__ = ["butterfly_apply", "sandwich_apply", "one_hot_select", "Backend",

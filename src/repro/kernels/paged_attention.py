@@ -163,7 +163,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, cur_pos, *,
         ],
     )
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name="paged_decode_attention",
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
     )(page_table, cur_pos, q, k_pool, v_pool)
@@ -206,23 +206,21 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     axes (:func:`_sharded_paged_decode`).
     """
     from repro.kernels import context as exctx
-    from repro.obs.profiling import annotate
     ctx = exctx.current_execution()
     if backend is None:
         backend = exctx.resolve_backend(ctx.backend if ctx else "auto")
-    with annotate("paged_attention"):
-        if backend == "jnp":
-            out = paged_attend_ref(q[:, None], k_pool, v_pool, page_table,
-                                   cur_pos[:, None])
-            return out[:, 0]
-        cur_pos = jnp.asarray(cur_pos, jnp.int32)
-        interpret = backend == "pallas_interpret"
-        if ctx is not None and ctx.mesh is not None:
-            from repro.runtime import butterfly_sharding as bsh
-            axes = bsh.data_axes(ctx.mesh, ctx.mesh_axes)
-            if axes:
-                return _sharded_paged_decode(
-                    q, k_pool, v_pool, page_table, cur_pos, mesh=ctx.mesh,
-                    axes=axes, interpret=interpret)
-        return _paged_decode_pallas(q, k_pool, v_pool, page_table, cur_pos,
-                                    interpret=interpret)
+    if backend == "jnp":
+        out = paged_attend_ref(q[:, None], k_pool, v_pool, page_table,
+                               cur_pos[:, None])
+        return out[:, 0]
+    cur_pos = jnp.asarray(cur_pos, jnp.int32)
+    interpret = backend == "pallas_interpret"
+    if ctx is not None and ctx.mesh is not None:
+        from repro.runtime import butterfly_sharding as bsh
+        axes = bsh.data_axes(ctx.mesh, ctx.mesh_axes)
+        if axes:
+            return _sharded_paged_decode(
+                q, k_pool, v_pool, page_table, cur_pos, mesh=ctx.mesh,
+                axes=axes, interpret=interpret)
+    return _paged_decode_pallas(q, k_pool, v_pool, page_table, cur_pos,
+                                interpret=interpret)

@@ -193,6 +193,7 @@ def _sandwich_fwd_call(x, b_in, sel_in, core, sel_out, b_out, scale_in,
         functools.partial(_sandwich_kernel, stages_in=num_stages(n1),
                           stages_out=num_stages(n2),
                           scale_in=scale_in, scale_out=scale_out),
+        name="sandwich_matmul_fwd",
         grid=grid,
         in_specs=_sandwich_specs(bb, n1, n2, p1, p2, k1, k2),
         out_specs=pl.BlockSpec((bb, n2), lambda i: (i, 0)),
@@ -230,6 +231,7 @@ def _sandwich_bwd_call(x, b_in, sel_in, core, sel_out, b_out, g, scale_in,
                           stages_out=stages_out, seg_in=seg_in,
                           seg_out=seg_out, scale_in=scale_in,
                           scale_out=scale_out),
+        name="sandwich_matmul_bwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[

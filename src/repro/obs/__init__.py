@@ -1,14 +1,25 @@
-"""``repro.obs`` — process-wide observability: span tracing + metrics.
+"""``repro.obs`` — process-wide observability: one tracing system plus
+metrics.
 
-Three pillars, all zero-dependency (stdlib only) so the serving tier can
-instrument itself without touching jax:
+Importing it never imports jax (stdlib only at import):
 
-* :mod:`repro.obs.tracing` — a bounded-ring :class:`Tracer` emitting
-  per-request and per-engine spans with wall-clock *and* deterministic
-  engine-tick timestamps, exported as Chrome trace-event JSON (loadable
-  in Perfetto / ``chrome://tracing``; one track per replica, one per
-  request). :data:`NULL_TRACER` is the always-installed no-op default, so
-  the tracing-off hot path costs a handful of no-op calls per tick.
+* :mod:`repro.obs.tracing` — one :class:`Tracer` with two outputs.
+  *For operators*: a bounded Chrome trace-event ring (Perfetto /
+  ``chrome://tracing``; one process row per replica, an engine lane and
+  one lane per request, each request's ``queue``, ``prefill`` and
+  ``decode`` spans once per request) with wall-clock *and* engine-tick
+  timestamps. *For the device trace*: :meth:`Tracer.phase` wraps each
+  leaf of the engine's tick and the trainer's step (``serve.admit``,
+  ``serve.chunk``, ``serve.first_token``, ``serve.decode``,
+  ``serve.readback``, ``serve.emit``, ``serve.compile``; ``train.batch``,
+  ``train.dispatch``, ``train.readback``) in a
+  ``jax.profiler.TraceAnnotation``, so a ``jax.profiler`` session records
+  them on the device's clock under their plain names, tracing on or off.
+  :data:`NULL_TRACER`, the default, only annotates. Device programs carry
+  stable names of their own (``jit_serve_decode``, ``jit_train_step``,
+  the Pallas kernels' ``name=``); kernel call sites are not annotated,
+  since they run under ``jit`` and an annotation there fires only while
+  tracing.
 * :mod:`repro.obs.registry` — typed :class:`Counter` / :class:`Gauge` /
   :class:`Histogram` primitives plus callback collectors in one
   lock-protected :class:`MetricsRegistry`, with a stable JSON snapshot
@@ -16,13 +27,6 @@ instrument itself without touching jax:
   exposition. The serving engine, router, cache pool, fault injector and
   compile cache all register into one registry — ONE machine-readable
   telemetry surface instead of five ad-hoc dicts.
-* :mod:`repro.obs.profiling` — ``jax.profiler.TraceAnnotation`` wrappers
-  around the fused butterfly / sandwich / flash / paged-attention kernel
-  call sites, gated on the ambient
-  :class:`repro.kernels.context.ExecutionContext` (``profile=True``), so
-  device profiles line up with the engine's span names. Imported lazily
-  by the kernel modules — importing ``repro.obs`` itself never imports
-  jax.
 
 :mod:`repro.obs.validate` structurally validates Chrome trace-event JSON
 (every event carries ``ph/ts/pid/tid/name``, complete spans properly

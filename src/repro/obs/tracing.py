@@ -1,11 +1,19 @@
 """Bounded-ring span tracer with Chrome trace-event JSON export.
 
-Zero dependencies (stdlib only). The serving tier emits spans through a
+Stdlib only at import. The serving tier emits spans through a
 :class:`Tracer` — per-request lifecycle spans (``queue``, ``admit``,
-``prefill_chunk[i]``, ``decode``, ``spec``, ``preempt``, ``finish`` …)
-and engine-level spans (``tick``, ``grow_pages``, ``compile``,
-``swap_checkpoint``) — and exports them as Chrome trace-event JSON that
-loads directly in Perfetto or ``chrome://tracing``.
+``prefill``, ``decode``, ``spec``, ``preempt``, ``finish`` …) and
+engine-level spans (``tick``, ``grow_pages``, ``swap_checkpoint`` and the
+phases below) — and exports them as Chrome trace-event JSON that loads
+directly in Perfetto or ``chrome://tracing``.
+
+:meth:`Tracer.phase` marks a leaf of the engine's or trainer's own host
+work (``serve.admit``, ``serve.decode``, ``train.dispatch`` …). It always
+enters ``jax.profiler.TraceAnnotation(name)``, so a profiler session
+records the phase on the device trace's clock under its plain name (about
+a microsecond without a session); an enabled tracer records the Chrome
+span as well. ``jax.profiler`` is imported on the first phase, never at
+import.
 
 Track layout: ``pid`` is the replica id (one process row per replica),
 ``tid`` 0 is the engine lane, and ``tid = rid + 1`` is the per-request
@@ -18,8 +26,9 @@ deterministic".
 
 Memory stays flat: the ring holds at most ``capacity`` events and counts
 what it evicts in :attr:`Tracer.dropped`. The :data:`NULL_TRACER`
-singleton is the default everywhere — every method is a no-op, so the
-tracing-off hot path pays only a handful of no-op calls per engine tick.
+singleton is the default everywhere — every method but :meth:`phase` is
+a no-op, so the tracing-off hot path pays a handful of no-op calls and
+one profiler annotation per phase.
 """
 
 from __future__ import annotations
@@ -37,27 +46,40 @@ __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "TRACK_ENGINE"]
 TRACK_ENGINE = 0
 
 
-class _Span:
-    """Context manager emitting one complete ("X") event on exit."""
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, imported on first use."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
-    __slots__ = ("_tracer", "_name", "_pid", "_tid", "_args", "_t0")
+
+class _Span:
+    """Context manager emitting one complete ("X") event on exit, inside
+    a profiler annotation when given one (:meth:`Tracer.phase`)."""
+
+    __slots__ = ("_tracer", "_name", "_pid", "_tid", "_args", "_t0",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, pid: int, tid: int,
-                 args: Dict[str, Any]):
+                 args: Dict[str, Any], ann=None):
         self._tracer = tracer
         self._name = name
         self._pid = pid
         self._tid = tid
         self._args = args
         self._t0 = 0.0
+        self._ann = ann
 
     def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = self._tracer.now()
         return self
 
     def __exit__(self, *exc) -> None:
         self._tracer.complete(self._name, self._t0, self._tracer.now(),
                               pid=self._pid, tid=self._tid, **self._args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 class _NullSpan:
@@ -139,6 +161,13 @@ class Tracer:
         """Context manager recording a complete span around its body."""
         return _Span(self, name, pid, tid, args)
 
+    def phase(self, name: str, *, pid: int = 0, tid: int = TRACK_ENGINE,
+              **args: Any) -> _Span:
+        """A leaf of the engine's or trainer's host work: a profiler
+        annotation named ``name`` (args stay out of its name) around a
+        complete span carrying ``args``."""
+        return _Span(self, name, pid, tid, args, _annotation(name))
+
     # -- track naming --------------------------------------------------
     def name_process(self, pid: int, name: str) -> None:
         """Label a process row (one per replica) in the trace viewer."""
@@ -205,9 +234,9 @@ class NullTracer(Tracer):
     """No-op tracer: every method returns immediately.
 
     Installed by default on every engine/router so the tracing-off hot
-    path stays unmeasurably slow — no locks, no allocation, no clock
-    reads. ``now()`` returns 0.0 (callers only ever feed it back into
-    the no-op ``complete``).
+    path stays cheap — no locks, no ring, no clock reads. ``now()`` returns 0.0 (callers only ever feed it back into
+    the no-op ``complete``). :meth:`phase` still annotates, so a profiler
+    session sees the phases with tracing off.
     """
 
     enabled = False
@@ -231,6 +260,9 @@ class NullTracer(Tracer):
 
     def span(self, name, *, pid=0, tid=TRACK_ENGINE, **args) -> _NullSpan:
         return _NULL_SPAN
+
+    def phase(self, name, *, pid=0, tid=TRACK_ENGINE, **args):
+        return _annotation(name)
 
     def name_process(self, pid, name) -> None:
         return None

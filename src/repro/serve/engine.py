@@ -139,14 +139,16 @@ class CompileCache:
     :meth:`counted_jit` wraps the pre-jit function so every retrace bumps
     ``traces[key]`` (the function body only executes while jax traces —
     cached executions never touch it). The serving tests gate on exactly
-    this counter: one trace per (shape, context), ever.
+    this counter: one trace per (shape, context), ever. The wrapper is
+    named after the key's kind (``serve_decode``, ``serve_chunk_prefill``
+    …), so the device program is ``jit_serve_<kind>`` in a profile.
 
     Cold compiles are structured events: the first call through a key is
-    timed and emitted as a ``compile`` span on the tracer's engine lane
-    (args carry the formatted key + wall seconds) and appended to
-    ``events``, so compile storms are visible per-replica in the Chrome
-    trace. The timing wrapper replaces itself after the first call, so
-    warm calls pay nothing.
+    timed, run inside the ``serve.compile`` phase (a Chrome span on the
+    tracer's engine lane whose args carry the formatted key) and appended
+    to ``events``, so compile storms are visible per-replica in the
+    Chrome trace. The timing wrapper replaces itself after the first
+    call, so warm calls pay nothing.
     """
 
     def __init__(self, tracer=None, pid: int = 0):
@@ -162,18 +164,15 @@ class CompileCache:
             inner = build()
 
             def cold(*args, __key=key, __inner=inner, **kwargs):
+                name = _fmt_compile_key(__key)
                 t0 = time.monotonic()
-                tt0 = self._tracer.now()
-                out = __inner(*args, **kwargs)
+                with self._tracer.phase("serve.compile", pid=self._pid,
+                                        key=name):
+                    out = __inner(*args, **kwargs)
                 self._fns[__key] = __inner   # warm path: straight through
-                dt = time.monotonic() - t0
                 self.events.append(
-                    {"key": _fmt_compile_key(__key),
-                     "seconds": round(dt, 6)})
-                self._tracer.complete(
-                    "compile", tt0, self._tracer.now(), pid=self._pid,
-                    tid=TRACK_ENGINE, cat="compile",
-                    key=_fmt_compile_key(__key), seconds=round(dt, 6))
+                    {"key": name,
+                     "seconds": round(time.monotonic() - t0, 6)})
                 return out
 
             fn = self._fns[key] = cold
@@ -183,6 +182,7 @@ class CompileCache:
         def traced(*args, **kwargs):
             self.traces[key] = self.traces.get(key, 0) + 1
             return fn(*args, **kwargs)
+        traced.__name__ = traced.__qualname__ = f"serve_{key[0]}"
         return jax.jit(traced, **jit_kw)
 
     @property
@@ -316,6 +316,9 @@ class _Slot:
     #                                        queue entry (submit / preempt
     #                                        requeue / adopt) — the start
     #                                        of the next "queue" span
+    lane: Optional[tuple] = None           # the request lane's open
+    #                                        "prefill" / "decode" span:
+    #                                        (name, tracer start, args)
 
     def __post_init__(self):
         if self.prefill_seq is None:
@@ -374,7 +377,9 @@ class ServeEngine:
       timeline (per-request lanes ``tid = rid + 1``, engine lane
       ``tid = 0``, process row ``pid = replica``). Default: the no-op
       :data:`~repro.obs.NULL_TRACER` — tracing off costs nothing
-      measurable (gated by the ``serve/trace_e2e`` bench row).
+      measurable (gated by the ``serve/trace_e2e`` bench row). Either
+      way the tick's phases (``serve.admit`` … ``serve.emit``) annotate
+      a ``jax.profiler`` session, about a microsecond each without one.
     * ``registry`` — a :class:`repro.obs.MetricsRegistry` this engine
       registers its collectors into (callbacks reading the live
       counters, labelled ``{"replica": str(replica)}``); pass one shared
@@ -886,7 +891,7 @@ class ServeEngine:
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._slots[i] = None
-                self._release_slot(i)
+                self._release_slot(i, s)
                 dead.append(s)
         self.metrics.sync_pool(self.pool)
         if dead:
@@ -968,18 +973,19 @@ class ServeEngine:
         tick = self.metrics.ticks
         t_wall = time.monotonic()
         tt0 = self.tracer.now()
-        self._process_cancels()
-        self._expire_deadlines()
-        self._admit()
-        self.metrics.on_occupancy(
-            sum(s is not None for s in self._slots))
-        if self.faults is not None:
-            # the mid-tick crash site: admissions landed, compute has not
-            # run — exactly where a device error would strand futures if
-            # the driver's abort path were broken
-            self.faults.check("engine.tick")
-        if self.admission == "incremental":
-            self._grow_pages()
+        with self._phase("serve.admit"):
+            self._process_cancels()
+            self._expire_deadlines()
+            self._admit()
+            self.metrics.on_occupancy(
+                sum(s is not None for s in self._slots))
+            if self.faults is not None:
+                # the mid-tick crash site: admissions landed, compute has
+                # not run — exactly where a device error would strand
+                # futures if the driver's abort path were broken
+                self.faults.check("engine.tick")
+            if self.admission == "incremental":
+                self._grow_pages()
         if self.prefill_chunk is not None:
             self._chunk_tick()
         if any(s is not None and s.decoding for s in self._slots):
@@ -1004,6 +1010,31 @@ class ServeEngine:
         return self.metrics.ticks - start
 
     # -- internals -----------------------------------------------------
+
+    def _phase(self, name: str, **args):
+        """One leaf of the tick's host work (``serve.*``): a profiler
+        annotation, and a span on the engine lane when tracing."""
+        return self.tracer.phase(name, pid=self.replica,
+                                 tick=self.metrics.ticks, **args)
+
+    def _open_lane(self, slot: _Slot, name: str, t: float,
+                   **args) -> None:
+        """Start the request lane's ``prefill`` or ``decode`` span at
+        ``t``, ending the one before it there."""
+        self._close_lane(slot, t)
+        slot.lane = (name, t, args)
+
+    def _close_lane(self, slot: _Slot, t: Optional[float] = None) -> None:
+        """End the request lane's open span, if any, at ``t`` (now): one
+        span per request and phase, whatever the number of ticks."""
+        if slot.lane is None:
+            return
+        name, t0, args = slot.lane
+        slot.lane = None
+        self.tracer.complete(name, t0, self.tracer.now() if t is None else t,
+                             pid=self.replica, tid=slot.rid + 1,
+                             rid=slot.rid, new_tokens=len(slot.tokens),
+                             **args)
 
     def _free_slot(self) -> Optional[int]:
         for i, s in enumerate(self._slots):
@@ -1086,10 +1117,12 @@ class ServeEngine:
             tok = int(self._first_token_fn()(
                 logits, jax.random.fold_in(self._key, slot.rid))[0])
         self.metrics.on_prefill_work(plen, time.monotonic() - t0)
-        self.tracer.complete("prefill", tt0, self.tracer.now(),
+        tt1 = self.tracer.now()
+        self.tracer.complete("prefill", tt0, tt1,
                              pid=self.replica, tid=slot.rid + 1,
                              rid=slot.rid, bucket=bucket, tokens=plen,
                              recompute=bool(slot.tokens))
+        self._open_lane(slot, "decode", tt1)
         if slot.tokens:
             # resumed after preemption: this prefill recomputed an
             # already-counted prefix, and the sampled token is the NEXT
@@ -1135,7 +1168,7 @@ class ServeEngine:
         for i, s in enumerate(self._slots):
             if s is not None and s.rid in rids:
                 self._slots[i] = None
-                self._release_slot(i)
+                self._release_slot(i, s)
                 hit.append(s)
         if hit:
             self.metrics.sync_pool(self.pool)
@@ -1180,7 +1213,7 @@ class ServeEngine:
             r = self._deadline_reason(s)
             if r is not None:
                 self._slots[i] = None
-                self._release_slot(i)
+                self._release_slot(i, s)
                 expired.append((s, r))
         if expired:
             self.metrics.sync_pool(self.pool)
@@ -1200,7 +1233,7 @@ class ServeEngine:
         the resumed output token-identical to a never-preempted run."""
         s = self._slots[idx]
         self._slots[idx] = None
-        self._release_slot(idx)
+        self._release_slot(idx, s)
         computed = (s.prefilled if s.prefilling
                     else int(s.prompt.size) + len(s.tokens))
         if s.tokens:
@@ -1282,72 +1315,71 @@ class ServeEngine:
         if not live:
             return
         C = self.prefill_chunk
-        tokens = np.zeros((self.slots, C), np.int32)
-        start = np.zeros((self.slots,), np.int32)
-        last = np.zeros((self.slots,), np.int32)
-        active = np.zeros((self.slots,), bool)
         spans = {}
-        for i, s in live:
-            lo = s.prefilled
-            hi = min(lo + C, int(s.prefill_seq.size))
-            tokens[i, :hi - lo] = s.prefill_seq[lo:hi]
-            start[i] = lo
-            last[i] = hi - lo - 1
-            active[i] = True
-            spans[i] = (lo, hi)
-        t0 = time.monotonic()
-        tt0 = self.tracer.now()
-        with self._scope():
-            logits, h_last, self._caches = self._chunk_fn()(
-                self._params, jnp.asarray(tokens), self._caches,
-                jnp.asarray(start), jnp.asarray(last),
-                jnp.asarray(active), self.pool.gather_args()["page_table"])
-        tt1 = self.tracer.now()
+        with self._phase("serve.chunk", slots=len(live)):
+            tt0 = self.tracer.now()
+            tokens = np.zeros((self.slots, C), np.int32)
+            start = np.zeros((self.slots,), np.int32)
+            last = np.zeros((self.slots,), np.int32)
+            active = np.zeros((self.slots,), bool)
+            for i, s in live:
+                lo = s.prefilled
+                hi = min(lo + C, int(s.prefill_seq.size))
+                tokens[i, :hi - lo] = s.prefill_seq[lo:hi]
+                start[i] = lo
+                last[i] = hi - lo - 1
+                active[i] = True
+                spans[i] = (lo, hi)
+                if lo == 0:
+                    self._open_lane(s, "prefill", tt0,
+                                    tokens=int(s.prefill_seq.size),
+                                    recompute=bool(s.tokens))
+            t0 = time.monotonic()
+            with self._scope():
+                logits, h_last, self._caches = self._chunk_fn()(
+                    self._params, jnp.asarray(tokens), self._caches,
+                    jnp.asarray(start), jnp.asarray(last),
+                    jnp.asarray(active), self.pool.gather_args()["page_table"])
         real = sum(hi - lo for lo, hi in spans.values())
         self.metrics.on_prefill_work(real, time.monotonic() - t0,
                                      chunked=True)
-        self.tracer.complete("prefill_chunk", tt0, tt1, pid=self.replica,
-                             tid=TRACK_ENGINE, slots=len(live),
-                             tokens=real, tick=self.metrics.ticks)
         for i, s in live:
-            lo, hi = spans[i]
-            self.tracer.complete(f"prefill_chunk[{lo // C}]", tt0, tt1,
-                                 pid=self.replica, tid=s.rid + 1,
-                                 rid=s.rid, lo=lo, hi=hi,
-                                 recompute=bool(s.tokens))
+            s.prefilled = spans[i][1]
+        done = [(i, s) for i, s in live if not s.prefilling]
+        if not done:
+            return
         finishers = []
-        anchors = np.asarray(h_last) if self.spec_k else None
-        for i, s in live:
-            lo, hi = spans[i]
-            s.prefilled = hi
-            if s.prefilling:
-                continue
-            with self._scope():
-                tok = int(self._first_token_fn()(
-                    logits[i:i + 1],
-                    jax.random.fold_in(self._key, s.rid))[0])
-            if s.tokens:
-                # resumed after preemption: the recomputed prefix already
-                # ends in generated tokens, so this is the NEXT token —
-                # and the request's one real prefill was already counted,
-                # so on_prefill_done would inflate `prefills`
-                self.metrics.on_token(s.rid)
-            else:
-                self.metrics.on_prefill_done()
-                self.metrics.on_first_token(s.rid)
-                self.tracer.instant("first_token", pid=self.replica,
-                                    tid=s.rid + 1, rid=s.rid,
-                                    tick=self.metrics.ticks)
-            s.tokens.append(tok)
-            s.last_token = tok
-            s.cur_pos = self._n_front + int(s.prefill_seq.size)
-            s.prefilled = -1                # decode phase
-            if anchors is not None:
-                s.anchor = anchors[i]       # draft seed for this tick
-            if self._finished(s):
-                finishers.append(i)
-        for i in finishers:
-            self._finish(i)
+        with self._phase("serve.first_token", slots=len(done)):
+            anchors = np.asarray(h_last) if self.spec_k else None
+            for i, s in done:
+                with self._scope():
+                    tok = int(self._first_token_fn()(
+                        logits[i:i + 1],
+                        jax.random.fold_in(self._key, s.rid))[0])
+                if s.tokens:
+                    # resumed after preemption: the recomputed prefix
+                    # already ends in generated tokens, so this is the
+                    # NEXT token — and the request's one real prefill was
+                    # already counted, so on_prefill_done would inflate
+                    # `prefills`
+                    self.metrics.on_token(s.rid)
+                else:
+                    self.metrics.on_prefill_done()
+                    self.metrics.on_first_token(s.rid)
+                    self.tracer.instant("first_token", pid=self.replica,
+                                        tid=s.rid + 1, rid=s.rid,
+                                        tick=self.metrics.ticks)
+                self._open_lane(s, "decode", self.tracer.now())
+                s.tokens.append(tok)
+                s.last_token = tok
+                s.cur_pos = self._n_front + int(s.prefill_seq.size)
+                s.prefilled = -1                # decode phase
+                if anchors is not None:
+                    s.anchor = anchors[i]       # draft seed for this tick
+                if self._finished(s):
+                    finishers.append(i)
+            for i in finishers:
+                self._finish(i)
 
     def _finished(self, slot: _Slot) -> bool:
         if len(slot.tokens) >= slot.req.max_new_tokens:
@@ -1355,14 +1387,16 @@ class ServeEngine:
         stop = slot.req.stop_token
         return stop is not None and slot.last_token == stop
 
-    def _release_slot(self, idx: int) -> None:
+    def _release_slot(self, idx: int, slot: _Slot) -> None:
         """The ONE scrub-then-free tail for every slot-exit path — finish,
-        cancel, deadline, preempt, abort. Under ``scrub_freed_slots`` the
+        cancel, deadline, preempt, abort — which also ends ``slot``'s open
+        lane span. Under ``scrub_freed_slots`` the
         slot's cache state is re-initialized BEFORE ``pool.free()``: after
         free() the slot's page-table row points at trash, so a late scrub
         would zero the trash page while the request's real KV survived in
         recycled pages (the stale-KV scrub-bypass bug the lifecycle paths
         used to have)."""
+        self._close_lane(slot)
         if self.scrub_freed_slots:
             with self._scope():
                 reset_args = [self._caches, jnp.asarray(idx, jnp.int32)]
@@ -1375,7 +1409,7 @@ class ServeEngine:
         slot = self._slots[idx]
         self._slots[idx] = None
         rm = self.metrics.on_finish(slot.rid)
-        self._release_slot(idx)
+        self._release_slot(idx, slot)
         self.metrics.sync_pool(self.pool)
         self.tracer.instant("finish", pid=self.replica,
                             tid=slot.rid + 1, rid=slot.rid,
@@ -1388,45 +1422,41 @@ class ServeEngine:
     def _decode_tick(self) -> None:
         if self.spec_k:
             return self._spec_decode_tick()
-        tokens = np.zeros((self.slots,), np.int32)
-        cur_pos = np.zeros((self.slots,), np.int32)
-        active = np.zeros((self.slots,), bool)
-        for i, s in enumerate(self._slots):
-            if s is None or s.prefilling:
-                continue
-            tokens[i] = s.last_token
-            cur_pos[i] = s.cur_pos
-            active[i] = True
-        n_active = int(active.sum())
-        rng = jax.random.fold_in(self._key, 0x5E57E9 + self.metrics.ticks)
-        t0 = time.monotonic()
-        tt0 = self.tracer.now()
-        step_args = [self._params, jnp.asarray(tokens), self._caches,
-                     jnp.asarray(cur_pos), rng, jnp.asarray(active)]
-        if self.pool.kind == "paged":
-            step_args.append(self.pool.gather_args()["page_table"])
-        with self._scope():
-            nxt, self._caches = self._decode_fn()(*step_args)
-        nxt = np.asarray(nxt)
-        tt1 = self.tracer.now()
-        self.metrics.on_decode_tick(n_active, n_active,
-                                    time.monotonic() - t0)
-        self.tracer.complete("decode", tt0, tt1, pid=self.replica,
-                             tid=TRACK_ENGINE, active=n_active,
-                             tick=self.metrics.ticks)
-        for i, s in enumerate(self._slots):
-            if s is None or s.prefilling:
-                continue
-            tok = int(nxt[i])
-            s.tokens.append(tok)
-            s.last_token = tok
-            s.cur_pos += 1
-            self.metrics.on_token(s.rid)
-            self.tracer.complete("decode", tt0, tt1, pid=self.replica,
-                                 tid=s.rid + 1, rid=s.rid, token=tok,
-                                 pos=s.cur_pos)
-            if self._finished(s):
-                self._finish(i)
+        with self._phase("serve.decode"):
+            tokens = np.zeros((self.slots,), np.int32)
+            cur_pos = np.zeros((self.slots,), np.int32)
+            active = np.zeros((self.slots,), bool)
+            for i, s in enumerate(self._slots):
+                if s is None or s.prefilling:
+                    continue
+                tokens[i] = s.last_token
+                cur_pos[i] = s.cur_pos
+                active[i] = True
+            n_active = int(active.sum())
+            rng = jax.random.fold_in(self._key,
+                                     0x5E57E9 + self.metrics.ticks)
+            t0 = time.monotonic()
+            step_args = [self._params, jnp.asarray(tokens), self._caches,
+                         jnp.asarray(cur_pos), rng, jnp.asarray(active)]
+            if self.pool.kind == "paged":
+                step_args.append(self.pool.gather_args()["page_table"])
+            with self._scope():
+                nxt, self._caches = self._decode_fn()(*step_args)
+        with self._phase("serve.readback", active=n_active):
+            nxt = np.asarray(nxt)
+        with self._phase("serve.emit"):
+            self.metrics.on_decode_tick(n_active, n_active,
+                                        time.monotonic() - t0)
+            for i, s in enumerate(self._slots):
+                if s is None or s.prefilling:
+                    continue
+                tok = int(nxt[i])
+                s.tokens.append(tok)
+                s.last_token = tok
+                s.cur_pos += 1
+                self.metrics.on_token(s.rid)
+                if self._finished(s):
+                    self._finish(i)
 
     def _spec_decode_tick(self) -> None:
         """Draft-k-verify-1: propose ``spec_k`` tokens per slot off each
@@ -1439,67 +1469,74 @@ class ServeEngine:
         never which tokens. A commit truncated below the accepted length
         (budget or stop token) always finishes the slot, so the verify
         anchor (valid only for full commits) is never used stale.
+
+        Phases: ``serve.decode`` holds both dispatches and the drafts'
+        read-back between them, ``serve.readback`` the verify results'.
         """
         live = [(i, s) for i, s in enumerate(self._slots)
                 if s is not None and s.decoding]
         if not live:
             return
         K1 = self.spec_k + 1
-        tokens = np.zeros((self.slots, K1), np.int32)
-        cur_pos = np.zeros((self.slots,), np.int32)
-        active = np.zeros((self.slots,), bool)
-        anchors = np.zeros((self.slots, self.cfg.d_model), np.float32)
-        for i, s in live:
-            tokens[i, 0] = s.last_token
-            cur_pos[i] = s.cur_pos
-            active[i] = True
-            anchors[i] = s.anchor
-        t0 = time.monotonic()
-        tt0 = self.tracer.now()
-        with self._scope():
-            drafts = self._draft_fn()(self._params, jnp.asarray(anchors),
-                                      jnp.asarray(tokens[:, 0]))
-            tokens[:, 1:] = np.asarray(drafts)
-            ttd = self.tracer.now()
-            targets, accepted, anchor_out, self._caches = \
-                self._spec_verify_fn()(
-                    self._params, jnp.asarray(tokens), self._caches,
-                    jnp.asarray(cur_pos), jnp.asarray(active),
-                    self.pool.gather_args()["page_table"])
-        tt1 = self.tracer.now()
+        with self._phase("serve.decode", slots=len(live)):
+            tokens = np.zeros((self.slots, K1), np.int32)
+            cur_pos = np.zeros((self.slots,), np.int32)
+            active = np.zeros((self.slots,), bool)
+            anchors = np.zeros((self.slots, self.cfg.d_model), np.float32)
+            for i, s in live:
+                tokens[i, 0] = s.last_token
+                cur_pos[i] = s.cur_pos
+                active[i] = True
+                anchors[i] = s.anchor
+            t0 = time.monotonic()
+            tt0 = self.tracer.now()
+            with self._scope():
+                drafts = self._draft_fn()(self._params,
+                                          jnp.asarray(anchors),
+                                          jnp.asarray(tokens[:, 0]))
+                tokens[:, 1:] = np.asarray(drafts)
+                ttd = self.tracer.now()
+                targets, accepted, anchor_out, self._caches = \
+                    self._spec_verify_fn()(
+                        self._params, jnp.asarray(tokens), self._caches,
+                        jnp.asarray(cur_pos), jnp.asarray(active),
+                        self.pool.gather_args()["page_table"])
+            tt1 = self.tracer.now()
         self.tracer.complete("spec_draft", tt0, ttd, pid=self.replica,
                              tid=TRACK_ENGINE, slots=len(live),
                              tick=self.metrics.ticks)
         self.tracer.complete("spec_verify", ttd, tt1, pid=self.replica,
                              tid=TRACK_ENGINE, slots=len(live),
                              tick=self.metrics.ticks)
-        targets = np.asarray(targets)
-        accepted = np.asarray(accepted)
-        anchor_out = np.asarray(anchor_out)
-        committed_total = 0
-        for i, s in live:
-            m = int(accepted[i]) + 1
-            m = min(m, s.req.max_new_tokens - len(s.tokens))
-            toks = [int(t) for t in targets[i, :m]]
-            stop = s.req.stop_token
-            if stop is not None and stop in toks:
-                toks = toks[:toks.index(stop) + 1]
-            s.tokens.extend(toks)
-            s.last_token = toks[-1]
-            s.cur_pos += len(toks)
-            s.anchor = anchor_out[i]
-            committed_total += len(toks)
-            self.metrics.on_token(s.rid, len(toks))
-            self.tracer.complete("spec", tt0, tt1, pid=self.replica,
-                                 tid=s.rid + 1, rid=s.rid,
-                                 drafted=self.spec_k,
-                                 accepted=int(accepted[i]),
-                                 committed=len(toks))
-        self.metrics.on_spec_tick(
-            drafted=len(live) * self.spec_k,
-            accepted=int(accepted[[i for i, _ in live]].sum()))
-        self.metrics.on_decode_tick(len(live), committed_total,
-                                    time.monotonic() - t0)
-        for i, s in live:
-            if self._finished(s):
-                self._finish(i)
+        with self._phase("serve.readback", slots=len(live)):
+            targets = np.asarray(targets)
+            accepted = np.asarray(accepted)
+            anchor_out = np.asarray(anchor_out)
+        with self._phase("serve.emit"):
+            committed_total = 0
+            for i, s in live:
+                m = int(accepted[i]) + 1
+                m = min(m, s.req.max_new_tokens - len(s.tokens))
+                toks = [int(t) for t in targets[i, :m]]
+                stop = s.req.stop_token
+                if stop is not None and stop in toks:
+                    toks = toks[:toks.index(stop) + 1]
+                s.tokens.extend(toks)
+                s.last_token = toks[-1]
+                s.cur_pos += len(toks)
+                s.anchor = anchor_out[i]
+                committed_total += len(toks)
+                self.metrics.on_token(s.rid, len(toks))
+                self.tracer.complete("spec", tt0, tt1, pid=self.replica,
+                                     tid=s.rid + 1, rid=s.rid,
+                                     drafted=self.spec_k,
+                                     accepted=int(accepted[i]),
+                                     committed=len(toks))
+            self.metrics.on_spec_tick(
+                drafted=len(live) * self.spec_k,
+                accepted=int(accepted[[i for i, _ in live]].sum()))
+            self.metrics.on_decode_tick(len(live), committed_total,
+                                        time.monotonic() - t0)
+            for i, s in live:
+                if self._finished(s):
+                    self._finish(i)

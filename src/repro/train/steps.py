@@ -37,7 +37,8 @@ def make_optimizer(tc: TrainConfig) -> opt.GradientTransformation:
 
 def make_train_step(cfg: ModelConfig, tx: opt.GradientTransformation,
                     microbatches: int = 1) -> Callable:
-    """Returns step(params, opt_state, batch) -> (params, opt_state, metrics).
+    """Returns train_step(params, opt_state, batch) -> (params, opt_state,
+    metrics); jitted, its program is ``jit_train_step``.
 
     ``microbatches > 1`` accumulates gradients over a lax.scan so peak
     activation memory scales with the microbatch, not the global batch —
@@ -47,7 +48,7 @@ def make_train_step(cfg: ModelConfig, tx: opt.GradientTransformation,
     def loss_fn(params, mb):
         return lm.loss_fn(cfg, params, mb)
 
-    def step(params, opt_state, batch):
+    def train_step(params, opt_state, batch):
         if microbatches == 1:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, batch)
@@ -85,7 +86,7 @@ def make_train_step(cfg: ModelConfig, tx: opt.GradientTransformation,
         out_metrics.update({k: v for k, v in metrics.items()})
         return params, opt_state, out_metrics
 
-    return step
+    return train_step
 
 
 def make_prefill_step(cfg: ModelConfig, chunks: int = 1) -> Callable:

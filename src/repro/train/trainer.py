@@ -1,7 +1,12 @@
 """Training loop: prefetched data, checkpoint/resume, straggler accounting.
 
 The Trainer is deliberately host-side thin: all math lives in the jitted
-step function; the loop does data, checkpoints, failure handling, logging.
+step function (``jit_train_step`` in a profile); the loop does data,
+checkpoints, failure handling, logging. Each step's host work is three
+``jax.profiler`` phases (:meth:`repro.obs.Tracer.phase`):
+``train.batch`` (the next batch, placed on the devices),
+``train.dispatch`` (the step's call) and ``train.readback`` (its loss
+read back, which waits for the device).
 """
 
 from __future__ import annotations
@@ -23,6 +28,7 @@ from repro.data.pipeline import Prefetcher, SyntheticLM, for_model
 from repro.kernels import context as exctx
 from repro.kernels import tuning
 from repro.models import lm
+from repro.obs.tracing import NULL_TRACER
 from repro.optim import optimizer as opt
 from repro.runtime import pytree as pt
 from repro.runtime import sharding as rsh
@@ -191,15 +197,18 @@ class Trainer:
         step_times: List[float] = []
         try:
             for i in range(start_step, start_step + steps):
-                step_idx, raw = next(prefetch)
-                batch = self._make_batch_arrays(raw)
+                with NULL_TRACER.phase("train.batch"):
+                    step_idx, raw = next(prefetch)
+                    batch = self._make_batch_arrays(raw)
                 t0 = time.monotonic()
                 # the sharding ctx must be live whenever the step function
                 # (re)traces — butterfly sites read the active mesh then
-                with self._sharding_scope():
+                with self._sharding_scope(), \
+                        NULL_TRACER.phase("train.dispatch"):
                     params, opt_state, metrics = self.step_fn(
                         params, opt_state, batch)
-                loss = float(metrics["loss"])
+                with NULL_TRACER.phase("train.readback"):
+                    loss = float(metrics["loss"])
                 dt = time.monotonic() - t0
                 straggler.record({"host0": dt})
                 losses.append(loss)

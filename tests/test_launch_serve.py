@@ -65,7 +65,8 @@ def test_cli_trace_out_and_metrics_interval(monkeypatch, tmp_path, capsys):
     doc = json.loads(trace.read_text())
     events = validate_chrome_trace(doc)
     names = {e["name"] for e in events}
-    assert {"queue", "admit", "tick", "finish", "compile"} <= names
+    assert {"queue", "admit", "prefill", "decode", "tick", "finish",
+            "serve.compile"} <= names
     finishes = [e for e in events if e["name"] == "finish"]
     assert len(finishes) == 3
     # one request lane per request, plus the engine lane

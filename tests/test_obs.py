@@ -12,11 +12,13 @@ Three pillars under test:
 
 The acceptance test drives a page-starved speculative engine through a
 one-replica :class:`Router` and reconstructs one preempted request's
-COMPLETE timeline from the exported trace: queue → admit →
-prefill_chunk[i] → spec → preempt → queue (again) → admit →
-prefill_chunk(recompute) → finish.
+COMPLETE timeline from the exported trace: queue → admit → prefill →
+spec → preempt → queue (again) → admit → prefill(recompute) → finish.
+:meth:`Tracer.phase` is checked under a real ``jax.profiler`` session:
+the profiler event carries the plain phase name.
 """
 
+import glob
 import json
 import threading
 
@@ -25,7 +27,6 @@ import pytest
 from repro.configs import registry as arch_registry
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry,
                        NullTracer, SNAPSHOT_SCHEMA, Tracer)
-from repro.obs.profiling import annotate, profiling_enabled
 from repro.obs.tracing import NULL_TRACER, TRACK_ENGINE
 from repro.obs.validate import TraceValidationError, validate_chrome_trace
 from repro.serve import (FaultInjector, Request, Router, SamplingParams,
@@ -395,15 +396,14 @@ def test_preempted_request_timeline_reconstructs(preempt_trace):
     i_queue, i_admit = names.index("queue"), names.index("admit")
     i_pre = names.index("preempt")
     assert i_queue < i_admit < i_pre
-    assert any(n.startswith("prefill_chunk[") for n in names[:i_pre])
+    assert "prefill" in names[:i_pre]
     # ... preempted mid-flight, then REQUEUED: a second queue span whose
     # admission recomputes the lost prefix ...
     tail = names[i_pre + 1:]
     assert "queue" in tail and "admit" in tail
     j = i_pre + 1 + tail.index("queue")
     assert lane[j]["args"]["resume"] is True
-    recompute = [e for e in lane[i_pre + 1:]
-                 if e["name"].startswith("prefill_chunk[")]
+    recompute = [e for e in lane[i_pre + 1:] if e["name"] == "prefill"]
     assert recompute and all(e["args"]["recompute"] for e in recompute)
     # ... and runs to completion
     assert names[-1] == "finish"
@@ -412,7 +412,7 @@ def test_preempted_request_timeline_reconstructs(preempt_trace):
     # speculative spans live on the engine lane
     engine_lane = {e["name"] for e in events if e["tid"] == TRACK_ENGINE}
     assert {"tick", "spec_draft", "spec_verify", "grow_pages",
-            "compile"} <= engine_lane
+            "serve.compile", "serve.decode"} <= engine_lane
 
     # lanes are labelled for Perfetto
     meta = {(e["name"], e.get("args", {}).get("name"))
@@ -431,7 +431,7 @@ def test_compile_cache_emits_structured_events(preempt_trace):
     for ev in events:
         assert set(ev) == {"key", "seconds"}
         assert isinstance(ev["key"], str) and ev["seconds"] >= 0
-    spans = [e for e in tr.events() if e["name"] == "compile"]
+    spans = [e for e in tr.events() if e["name"] == "serve.compile"]
     assert len(spans) == len(events)
     assert all(s["args"]["key"] == ev["key"]
                for s, ev in zip(spans, events))
@@ -465,40 +465,69 @@ def test_reset_metrics_rebases_pool_hwm_and_clears_trace(preempt_trace):
 
 
 # ---------------------------------------------------------------------------
-# Profiling hooks
+# Phases: profiler annotations on the device trace's clock
 # ---------------------------------------------------------------------------
 
-def test_annotate_gates_on_execution_context(monkeypatch):
-    from repro.kernels.context import ExecutionContext, use_execution
-
-    monkeypatch.delenv("REPRO_PROFILE", raising=False)
-    assert not profiling_enabled()
-    # off: the shared nullcontext, no jax.profiler import
-    assert annotate("x") is annotate("y")
-    with use_execution(ExecutionContext(profile=True)):
-        assert profiling_enabled()
-        cm = annotate("butterfly_matmul")
-        assert cm is not annotate.__globals__["_NULL"]
-        with cm:  # TraceAnnotation works outside an active profiler
-            pass
-        # explicit ctx wins over ambient
-        assert not profiling_enabled(ExecutionContext(profile=False))
-    monkeypatch.setenv("REPRO_PROFILE", "1")
-    assert profiling_enabled()
-    # a set ctx.profile beats the env fallback
-    assert not profiling_enabled(ExecutionContext(profile=False))
-
-
-def test_profiled_kernel_result_unchanged(cfg, params):
+def test_null_tracer_phase_only_annotates():
+    import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels.context import ExecutionContext, use_execution
-    from repro.models import lm
+    x = jnp.arange(6.0)
+    with NULL_TRACER.phase("serve.decode", pid=0, tick=3) as ann:
+        y = np.asarray(x * 2)
+    np.testing.assert_array_equal(y, np.arange(6.0) * 2)
+    assert type(ann).__name__ == "TraceAnnotation"
+    assert len(NULL_TRACER) == 0 and NULL_TRACER.emitted == 0
 
-    tokens = np.arange(1, 7, dtype=np.int32)[None, :]
-    caches = lm.init_caches(cfg, 1, 16)
-    logits, _ = lm.prefill(cfg, params, {"tokens": tokens}, caches)
-    with use_execution(ExecutionContext(profile=True)):
-        caches2 = lm.init_caches(cfg, 1, 16)
-        logits2, _ = lm.prefill(cfg, params, {"tokens": tokens}, caches2)
-    np.testing.assert_array_equal(np.asarray(logits), np.asarray(logits2))
+
+def test_tracer_phase_records_span_and_plain_profiler_event(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.phase("serve.decode", pid=1, tick=7):
+            with tr.phase("serve.compile", pid=1, key="k"):
+                pass
+        with NULL_TRACER.phase("train.readback", step=2):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    spans = tr.events()
+    assert [e["name"] for e in spans] == ["serve.compile", "serve.decode"]
+    assert spans[1]["args"] == {"tick": 7} and spans[1]["pid"] == 1
+    validate_chrome_trace(tr.chrome_trace())
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    names = {ev.name for p in ProfileData.from_file(path).planes
+             for line in p.lines for ev in line.events}
+    # the args stay in the Chrome span: the profiler event is the plain
+    # phase name, traced or not
+    assert {"serve.decode", "serve.compile", "train.readback"} <= names
+    assert not any(n.startswith("serve.decode#") for n in names)
+
+
+def test_one_prefill_and_decode_span_per_request(cfg, params):
+    """A request's lane holds one ``prefill`` span (over all its chunks)
+    and one ``decode`` span (first token to finish), whatever the number
+    of ticks; the engine lane holds the tick's phases."""
+    tr = Tracer()
+    eng = ServeEngine(cfg, params, slots=2, max_len=64, prefill_chunk=4,
+                      seed=0, tracer=tr)
+    futs = [eng.submit(Request(prompt=list(range(1, n)), max_new_tokens=6))
+            for n in (11, 4, 7)]
+    eng.run_until_idle()
+    assert all(len(f.result(0).tokens) == 6 for f in futs)
+    events = validate_chrome_trace(tr.chrome_trace())
+    for rid in range(3):
+        lane = [e for e in events if e["tid"] == rid + 1 and e["ph"] == "X"]
+        assert [e["name"] for e in lane] == ["queue", "prefill", "decode"]
+        prefill, decode = lane[1], lane[2]
+        assert prefill["ts"] + prefill["dur"] <= decode["ts"] + 1e-3
+        assert decode["args"]["new_tokens"] == 6
+    engine = {e["name"] for e in events if e["tid"] == TRACK_ENGINE}
+    assert {"serve.admit", "serve.chunk", "serve.first_token",
+            "serve.decode", "serve.readback", "serve.emit"} <= engine
+    assert not any(e["name"] == "decode" for e in events
+                   if e["tid"] == TRACK_ENGINE)

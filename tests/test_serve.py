@@ -197,6 +197,63 @@ def test_chunked_prefill_compiles_once_for_all_lengths(cfg, params):
     assert eng.compile_stats["compiles"] == 3
 
 
+def test_counted_jit_names_the_program_after_its_kind(cfg, params):
+    """Each device program is named after its compile key's kind
+    (``jit_serve_decode``, not ``jit_traced``), and the trace counter
+    still counts one trace per key."""
+    from repro.serve.engine import CompileCache
+
+    lowered = CompileCache().counted_jit(("decode", "arch"),
+                                         lambda x: x * 2).lower(jnp.ones(3))
+    assert "module @jit_serve_decode" in lowered.as_text()
+    cc = CompileCache()
+    key = ("decode", "arch", 2)
+    fn = cc.get(key, lambda: cc.counted_jit(key, lambda x: x * 2))
+    for _ in range(3):
+        np.testing.assert_array_equal(np.asarray(fn(jnp.ones(3))), 2.0)
+    assert cc.traces == {key: 1}
+
+    eng = ServeEngine(cfg, params, slots=2, max_len=64, seed=0)
+    eng.submit(_req(_prompt(np.random.default_rng(5), cfg, 20), max_new=3))
+    eng.run_until_idle()
+    names = {k[0]: fn.__name__ for k, fn in eng.compile_cache._fns.items()}
+    assert names == {"chunk_prefill": "serve_chunk_prefill",
+                     "decode": "serve_decode", "sample": "serve_sample"}
+    assert all(n == 1 for n in eng.compile_stats["traces"].values())
+
+
+def test_engine_tick_phases_in_order_without_overlap(cfg, params):
+    """Every tick's host work is a row of ``serve.*`` phases in the
+    tick's order — admit, chunk, first token, decode, read-back, emit —
+    that never overlap; only a cold compile nests inside one."""
+    from repro.obs import Tracer
+
+    tr = Tracer()
+    eng = ServeEngine(cfg, params, slots=2, max_len=64, seed=0, tracer=tr)
+    rng = np.random.default_rng(6)
+    futs = [eng.submit(_req(_prompt(rng, cfg, n), max_new=3))
+            for n in (20, 5)]
+    eng.run_until_idle()
+    assert all(len(f.result(0).tokens) == 3 for f in futs)
+    order = ["serve.admit", "serve.chunk", "serve.first_token",
+             "serve.decode", "serve.readback", "serve.emit"]
+    phases = [e for e in tr.events() if e["name"].startswith("serve.")]
+    compiles = [e for e in phases if e["name"] == "serve.compile"]
+    leaves = sorted((e for e in phases if e["name"] != "serve.compile"),
+                    key=lambda e: e["ts"])
+    by_tick = {}
+    for e in leaves:
+        by_tick.setdefault(e["args"]["tick"], []).append(e["name"])
+    assert by_tick == {0: order, 1: order, 2: ["serve.admit"] + order[3:]}
+    for a, b in zip(leaves, leaves[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-3, (a, b)
+    assert len(compiles) == 3
+    for c in compiles:
+        assert any(p["ts"] <= c["ts"] and
+                   c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-3
+                   for p in leaves)
+
+
 def test_bucketed_prefill_compiles_once_per_bucket(cfg, params):
     eng = ServeEngine(cfg, params, slots=2, max_len=64, seed=0,
                       pool="dense")

@@ -26,6 +26,40 @@ def test_training_reduces_loss(tmp_path):
     assert np.mean(res.losses[-5:]) < np.mean(res.losses[:5]) - 0.3
 
 
+def test_step_phases_and_program_name(tmp_path):
+    """Each step's host work is three profiler phases in order — batch,
+    dispatch, read-back — and the step's device program is
+    ``jit_train_step``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg = registry.get("smollm-135m-smoke")
+    tc = TrainConfig(learning_rate=3e-3, warmup_steps=1, total_steps=4,
+                     checkpoint_every=0, checkpoint_dir="")
+    tr = Trainer(cfg, tc, seq_len=16, global_batch=2)
+    tr.run(1)                       # compile outside the profiled steps
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = tr.run(2, params=tr.params, opt_state=tr.opt_state)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(res.losses) == 2
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    evs = sorted((int(ev.start_ns), int(ev.duration_ns), ev.name)
+                 for p in ProfileData.from_file(path).planes
+                 for line in p.lines for ev in line.events
+                 if ev.name.startswith("train."))
+    assert [n for _, _, n in evs] == [
+        "train.batch", "train.dispatch", "train.readback"] * 2
+    for (s0, d0, _), (s1, _, _) in zip(evs, evs[1:]):
+        assert s0 + d0 <= s1
+    batch = tr._make_batch_arrays(tr.data.batch(0))
+    assert "module @jit_train_step" in tr.step_fn.lower(
+        tr.params, tr.opt_state, batch).as_text()
+
+
 def test_checkpoint_resume_continues(tmp_path):
     cfg = registry.get("smollm-135m-smoke")
     tc = TrainConfig(learning_rate=3e-3, warmup_steps=5, total_steps=60,
