@@ -6,7 +6,7 @@ published width, with the fused Pallas kernels compiled for the chip.
     python chip_smoke.py               # one chip: serve, reference, train
     python chip_smoke.py --four-chips  # four chips: multi-chip paths only
 
-One chip, three phases, each printing one line:
+One chip, four phases, each printing one line:
 
 * serve — seeded fresh weights (``loader.load_for_serving``, no checkpoint)
   behind a one-replica ``Router`` built as ``python -m repro.launch.serve``
@@ -23,6 +23,11 @@ One chip, three phases, each printing one line:
   holds |pallas - jnp| within twice the oracle's own bf16 error.
 * train — five ``Trainer`` steps at ``seq_len`` 1024, batch 8, through the
   fused backward kernels; every loss must be finite.
+* grads — the sandwich kernel in float32 at the MLP sites' widths
+  (1024 -> 2048 and back, where every butterfly folds its in-lane stages
+  onto the MXU) over four row tiles: its output and its four gradients
+  against the float32 oracle at full matmul precision, within
+  ``GRAD_BOUND`` of each tensor's largest entry.
 
 ``--four-chips`` runs only what exists across chips, each against one
 device, at full width with the depth cut to ``FOUR_CHIP_LAYERS`` layers:
@@ -54,6 +59,7 @@ ARCH = "smollm-135m-butterfly"
 SEED = 0
 CHUNK = 256                 # prefill chunk: prompts up to 1024 span 4
 LOSS_BOUND = 1e-2           # bf16: |sharded - one device| / one device
+GRAD_BOUND = 1e-5           # float32: max |kernel - oracle| / max |oracle|
 FOUR_CHIP_LAYERS = 4
 
 
@@ -233,6 +239,62 @@ def phase_train(cfg, device) -> None:
           f"peak_bytes_in_use={peak_bytes(device)}", flush=True)
 
 
+def phase_grads(device) -> None:
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core import butterfly as bf
+    from repro.kernels import ref, tuning
+    from repro.kernels.sandwich import one_hot_select, sandwich_matmul
+
+    parts = []
+    for n1, n2 in ((1024, 2048), (2048, 1024)):
+        k1, k2 = round(math.log2(n1)), round(math.log2(n2))
+        keys = jax.random.split(jax.random.PRNGKey(SEED), 4)
+        args = (jax.random.normal(keys[0], (256, n1)),
+                bf.random_weights(keys[1], n1),
+                jax.random.normal(keys[2], (k2, k1)) / math.sqrt(k1),
+                bf.random_weights(keys[3], n2))
+        c = jax.random.normal(jax.random.PRNGKey(SEED + 1), (256, n2))
+        rng = np.random.default_rng(SEED)
+        sel_in = one_hot_select(np.sort(rng.choice(n1, k1, replace=False)),
+                                n1)
+        sel_out = one_hot_select(np.sort(rng.choice(n2, k2, replace=False)),
+                                 n2).T
+        s_in, s_out = math.sqrt(n1 / k1), math.sqrt(n2 / k2)
+
+        def fused(x, b_in, core, b_out):
+            return sandwich_matmul(x, b_in, sel_in, core, sel_out, b_out,
+                                   scale_in=s_in, scale_out=s_out,
+                                   block_b=64)
+
+        def oracle(x, b_in, core, b_out):
+            return ref.sandwich_ref(x, b_in, core, b_out, sel_in, sel_out,
+                                    s_in, s_out)
+
+        def out_and_grads(f):
+            return (jax.jit(f)(*args),) + jax.jit(jax.grad(
+                lambda *a: jnp.vdot(c, f(*a)), (0, 1, 2, 3)))(*args)
+
+        got = out_and_grads(fused)
+        with jax.default_matmul_precision("highest"):
+            want = out_and_grads(oracle)
+        errs = [float(jnp.abs(g - w).max() / jnp.abs(w).max())
+                for g, w in zip(got, want)]
+        folded = (tuning.folded_stages(n1), tuning.folded_stages(n2))
+        parts.append(f"{n1}->{n2} folded={folded[0]}/{folded[1]} out, dx, "
+                     f"db_in, dcore, db_out " + ", ".join(
+                         f"{e:.2e}" for e in errs))
+        check(max(errs) <= GRAD_BOUND,
+              f"sandwich {n1}->{n2}: kernel and float32 oracle differ by "
+              f"{max(errs):.3g} of the largest entry (bound {GRAD_BOUND})")
+    print("grads: float32 sandwich kernel against the oracle, max |diff| / "
+          "max |oracle| | " + " | ".join(parts)
+          + f" | peak_bytes_in_use={peak_bytes(device)}", flush=True)
+
+
 # ---------------------------------------------------------------------------
 # Four chips
 # ---------------------------------------------------------------------------
@@ -401,6 +463,7 @@ def main() -> int:
             items = phase_serve(cfg, params, device)
             phase_reference(cfg, params, items[1].prompt, device)
             phase_train(cfg, device)
+            phase_grads(device)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -7,12 +7,26 @@ intensity ~1), a single ``pallas_call`` keeps a ``(block_b, n)`` activation
 tile resident in VMEM and applies *all* stages before writing back.
 
 Stage ``s`` is ``y = a_s ⊙ x + b_s ⊙ swap_s(x)`` with ``swap_s(x)[i] =
-x[i ^ 2**s]`` — VPU FMA and lane-rotate traffic only, no gather/scatter.
-The tile lives in a float32 VMEM buffer that every stage updates in place,
-``CHUNK`` lanes at a time: partners closer than a chunk are swapped by two
-lane rotations and a select, farther partners pair two chunks up. Stages
-and chunks are loops, not unrolled, so the traced kernel is the same size
-at n = 1024 and n = 65536.
+x[i ^ 2**s]``. Two engines share the stages:
+
+* **The MXU takes the in-lane stages.** Stages s = 0…6 (strides 1…64)
+  never mix two 128-lane groups, so composed they are one dense 128×128
+  matrix per lane group. :func:`fold_block` builds them from the weights
+  (plain jnp, outside the kernel) as a float32 ``(128, n)`` array ``M``
+  whose column block ``g`` is group ``g``'s matrix, and the kernel applies
+  them as ``x[:, g] @ M[:, g]``: float32 operands, float32 result,
+  ``precision=HIGHEST`` (float32-faithful products). ``B`` applies them
+  first, ``Bᵀ`` (strides reversed) last. Whether an ``n``-wide butterfly
+  folds is decided by ``n`` alone (:func:`repro.kernels.tuning.folded_stages`):
+  where ``n ≥ 128`` and the block, double-buffered with its gradient, fits
+  the VMEM budget — every width up to 4096, not the LM head's 65,536.
+* **The VPU takes the rest** (all stages where the butterfly does not
+  fold): VPU FMA and lane-rotate traffic only, no gather/scatter. The tile
+  lives in a float32 VMEM buffer that every stage updates in place,
+  ``CHUNK`` lanes at a time: partners closer than a chunk are swapped by
+  two lane rotations and a select, farther partners pair two chunks up.
+  Stages and chunks are loops, not unrolled, so the traced kernel is the
+  same size at n = 1024 and n = 65536.
 
 Training support: ``butterfly_matmul`` carries a :func:`jax.custom_vjp` whose
 backward pass is itself a fused Pallas kernel. The butterfly backward is a
@@ -22,25 +36,33 @@ reductions::
     da_s = Σ_batch g_{s+1} ⊙ x_s        db_s = Σ_batch g_{s+1} ⊙ swap_s(x_s)
     g_s  = a_s ⊙ g_{s+1} + swap_s(b_s ⊙ g_{s+1})
 
-**Segmented stage checkpointing.** The reverse sweep needs the stage inputs
-``x_s`` in *reverse* order. Recomputing each from the saved input tile costs
-O(p²) stage applications per tile (p = log2 n — 13× more VPU work than the
-forward at n = 8192). Instead, one forward sweep stashes the activation at
-every ``segment``-th stage boundary in a VMEM scratch buffer
-(``pl.pallas_call`` ``scratch_shapes``), and the reverse sweep recomputes
-only *within* a segment (one pass per segment, into a second scratch):
+The folded block's VJP is two MXU products per lane group, ``dU = dV·Mᵀ``
+and ``dM += Uᵀ·dV`` (``U`` its input, ``dV`` its output's cotangent), with
+``dM`` accumulated across the batch grid like the stage weights'
+gradients. The folded stages' weight gradients are the VJP of
+:func:`fold_block` at the kernel's ``dM``, taken after the call: weight-only
+and exact.
 
-    stage applications per tile  ≤  p (checkpoint sweep)
-                                  + p (within-segment recompute)
-                                  + p (dual cotangent sweep)   = O(p)
+**Segmented stage checkpointing.** The reverse sweep needs the VPU stages'
+inputs ``x_s`` in *reverse* order. Recomputing each from the saved input
+tile costs O(p²) stage applications per tile. Instead, one forward sweep
+stashes the activation at every ``segment``-th stage boundary in a VMEM
+scratch buffer (``pl.pallas_call`` ``scratch_shapes``), and the reverse
+sweep recomputes only *within* a segment (one pass per segment, into a
+second scratch). With ``p'`` VPU stages:
 
-against ``⌈p/segment⌉ + segment + 2`` float32 ``(block_b, n)`` VMEM tiles —
-``segment`` is the VMEM/compute knob, defaulting to ⌈√p⌉ (the live-tile
+    stage applications per tile  ≤  p' (checkpoint sweep)
+                                  + p' (within-segment recompute)
+                                  + p' (dual cotangent sweep)   = O(p')
+
+against ``⌈p'/segment⌉ + segment + 2`` float32 ``(block_b, n)`` VMEM tiles —
+``segment`` is the VMEM/compute knob, defaulting to ⌈√p'⌉ (the live-tile
 minimum) via :mod:`repro.kernels.tuning`, which also sizes ``block_b`` so
 the whole working set fits the VMEM budget. Weight gradients are accumulated
 in float32 across the batch grid: the TPU grid is sequential, so the
-``(p, 2, n)`` output block is revisited by every grid step and updated in
-place.
+gradient output blocks are revisited by every grid step and updated in
+place. Blocks that every grid step revisits (weights, ``M``, their
+gradients) are held in VMEM once (``pl.Buffered(1)``), not double-buffered.
 """
 
 from __future__ import annotations
@@ -53,7 +75,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.butterfly import num_stages
+from repro.core.butterfly import num_stages, stage_swap
 from repro.kernels import tuning
 
 # Run-time instrumentation: while count_stage_applies() is open, every
@@ -181,9 +203,10 @@ def _rowsum(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(x, axis=0, keepdims=True)
 
 
-def _stage_of(j, stages: int, transpose: bool):
-    """Stage applied at position ``j`` (Bᵀ applies the strides reversed)."""
-    return stages - 1 - j if transpose else j
+def _stage_of(j, lo: int, hi: int, transpose: bool):
+    """Stage applied at position ``j`` of the VPU stages ``[lo, hi)`` (Bᵀ
+    applies the strides reversed)."""
+    return hi - 1 - j if transpose else lo + j
 
 
 def _stage(buf, w_ref, s, transpose: bool, *, t_ref=None, dw_ref=None):
@@ -250,58 +273,128 @@ def _stage(buf, w_ref, s, transpose: bool, *, t_ref=None, dw_ref=None):
                      pairs)
 
 
-def _apply_stages(buf, w_ref, j0, j1, stages: int,
-                  transpose: bool) -> None:
-    """Application positions ``[j0, j1)`` in place on ``buf``, as one
-    loop."""
+def _apply_stages(buf, w_ref, lo: int, hi: int, transpose: bool) -> None:
+    """The VPU stages ``[lo, hi)`` in place on ``buf``, in application
+    order, as one loop."""
     def step(j, carry):
-        _stage(buf, w_ref, _stage_of(j, stages, transpose), transpose)
+        _stage(buf, w_ref, _stage_of(j, lo, hi, transpose), transpose)
         return carry
 
-    jax.lax.fori_loop(j0, j1, step, 0)
+    jax.lax.fori_loop(0, hi - lo, step, 0)
 
 
-def _butterfly_kernel(x_ref, w_ref, o_ref, buf, *, stages: int,
+def _dot(a, b, contract=(1, 0), precision=None):
+    """float32 MXU product contracting ``a``'s axis ``contract[0]`` with
+    ``b``'s axis ``contract[1]`` (transposes ride the matmul, not a copy)."""
+    return jax.lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32),
+        (((contract[0],), (contract[1],)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
+
+
+def _mxu(a, b, contract=(1, 0)):
+    """float32-faithful product (``precision=HIGHEST``): the fold block's."""
+    return _dot(a, b, contract, jax.lax.Precision.HIGHEST)
+
+
+def _for_groups(n: int, body) -> None:
+    """``body(cols)`` for every 128-lane group of an ``n``-wide tile, as one
+    loop."""
+    def step(g, carry):
+        body(_cols(g, _LANES))
+        return carry
+
+    jax.lax.fori_loop(0, n // _LANES, step, 0)
+
+
+def _fold(buf, m_ref) -> None:
+    """The folded stages in place on ``buf``: ``buf[:, g] = buf[:, g] @
+    M[:, g]`` for every lane group ``g`` (:func:`fold_block`)."""
+    def body(cols):
+        buf[:, cols] = _mxu(buf[:, cols], m_ref[:, cols])
+    _for_groups(buf.shape[-1], body)
+
+
+def _fold_vjp(u_ref, gbuf, m_ref, dm_ref) -> None:
+    """VJP of :func:`_fold` on one tile: ``u_ref`` holds the fold's input
+    and ``gbuf`` its output's cotangent, which becomes the input's
+    (``G·Mᵀ``); ``Uᵀ·G`` is added into ``dm_ref``, per lane group."""
+    def body(cols):
+        g = gbuf[:, cols]
+        dm_ref[:, cols] += _mxu(u_ref[:, cols], g, (0, 0))
+        gbuf[:, cols] = _mxu(g, m_ref[:, cols], (1, 1))
+    _for_groups(gbuf.shape[-1], body)
+
+
+def _butterfly_forward(buf, w_ref, m_ref, lo: int, hi: int,
+                       transpose: bool) -> None:
+    """The whole butterfly in place on the float32 tile ``buf``: the folded
+    stages on the MXU (``m_ref``; None where the butterfly does not fold)
+    and the VPU stages ``[lo, hi)`` — B folds first, Bᵀ last."""
+    if m_ref is not None and not transpose:
+        _fold(buf, m_ref)
+    _apply_stages(buf, w_ref, lo, hi, transpose)
+    if m_ref is not None and transpose:
+        _fold(buf, m_ref)
+
+
+def _butterfly_kernel(x_ref, w_ref, *refs, lo: int, hi: int,
                       transpose: bool):
     # stage math runs in float32: the TPU rotates only 32-bit lanes, and
     # v5e's VPU has no bfloat16 arithmetic anyway
+    m_ref, o_ref, buf = refs if lo else (None, *refs)
     _copy(buf, x_ref)
-    _apply_stages(buf, w_ref, 0, stages, stages, transpose)
+    _butterfly_forward(buf, w_ref, m_ref, lo, hi, transpose)
     _copy(o_ref, buf)
 
 
-def _butterfly_bwd_block(buf, gbuf, w_ref, stages: int, transpose: bool,
-                         segment: int, ckpt_ref, acts_ref, dw_ref) -> None:
-    """VJP of the fused butterfly on one tile, in place: ``buf`` holds the
-    input activation (clobbered), ``gbuf`` the output cotangent, which
-    becomes ``dx``; the tile's weight gradient is added into ``dw_ref``.
+def _butterfly_bwd_block(buf, gbuf, w_ref, lo: int, hi: int,
+                         transpose: bool, segment: int, ckpt_ref, acts_ref,
+                         dw_ref, top=None) -> None:
+    """VJP of the VPU stages ``[lo, hi)`` on one tile, in place: ``buf``
+    holds their input activation (clobbered), ``gbuf`` their output's
+    cotangent, which becomes the input's; the tile's weight gradient is
+    added into ``dw_ref``. ``top()``, if given, runs once ``buf`` holds the
+    stages' output, before the dual sweep reads ``gbuf``: Bᵀ's folded
+    stages come after its VPU stages.
 
     ``w_ref`` and ``dw_ref`` hold ``(2p, n)`` rows (:func:`_stage_weights`).
     Stage inputs come from segmented checkpointing: a forward sweep stores
     the activation entering position ``j`` for every segment boundary
     ``j ∈ {0, segment, 2·segment, …}`` in ``ckpt_ref``, then each segment
     is recomputed exactly once, into ``acts_ref``, during the reverse sweep
-    — O(p) stage applications total instead of the O(p²) full-prefix
-    recompute.
+    — O(p') stage applications total instead of the O(p'²) full-prefix
+    recompute. With ``top``, the forward sweep runs through the last
+    segment too, to reach the output.
 
     The cotangent rule per stage is the *dual* stage applied to ``g``: the
     transpose of ``a ⊙ x + b ⊙ swap(x)`` is ``a ⊙ g + swap(b ⊙ g)`` and vice
     versa (swap is an involution).
     """
+    stages = hi - lo
+    if stages == 0:
+        if top is not None:
+            top()
+        return
     seg = tuning.resolve_segment(stages, segment or None)
     n_seg = -(-stages // seg)
 
     def stage(j, carry):
-        _stage(buf, w_ref, _stage_of(j, stages, transpose), transpose)
+        _stage(buf, w_ref, _stage_of(j, lo, hi, transpose), transpose)
         return carry
 
     # --- forward sweep: checkpoint the input of every segment ---
     def checkpoint(ci, carry):
         _copy(ckpt_ref.at[ci], buf)
-        return jax.lax.fori_loop(ci * seg, (ci + 1) * seg, stage, carry)
+        return jax.lax.fori_loop(ci * seg, jnp.minimum((ci + 1) * seg,
+                                                       stages), stage, carry)
 
-    jax.lax.fori_loop(0, n_seg - 1, checkpoint, 0)
-    _copy(ckpt_ref.at[n_seg - 1], buf)
+    if top is None:
+        jax.lax.fori_loop(0, n_seg - 1, checkpoint, 0)
+        _copy(ckpt_ref.at[n_seg - 1], buf)
+    else:
+        jax.lax.fori_loop(0, n_seg, checkpoint, 0)
+        top()
 
     # --- reverse sweep: one within-segment recompute per segment ---
     def segment_vjp(k, carry):
@@ -319,7 +412,7 @@ def _butterfly_bwd_block(buf, gbuf, w_ref, stages: int, transpose: bool,
 
         def dual(i, carry):
             j = j1 - 1 - i
-            _stage(gbuf, w_ref, _stage_of(j, stages, transpose), transpose,
+            _stage(gbuf, w_ref, _stage_of(j, lo, hi, transpose), transpose,
                    t_ref=acts_ref.at[j - j0], dw_ref=dw_ref)
             return carry
 
@@ -328,23 +421,52 @@ def _butterfly_bwd_block(buf, gbuf, w_ref, stages: int, transpose: bool,
     jax.lax.fori_loop(0, n_seg, segment_vjp, 0)
 
 
+def _butterfly_vjp(buf, gbuf, x_ref, w_ref, m_ref, dw_ref, dm_ref, lo: int,
+                   hi: int, transpose: bool, segment: int, ckpt_ref,
+                   acts_ref) -> None:
+    """VJP of the whole butterfly on one tile, in place: ``buf`` holds its
+    input (clobbered; B, whose folded stages come first, reads it again
+    from ``x_ref``), ``gbuf`` the output's cotangent, which becomes ``dx``.
+    Weight gradients go into ``dw_ref`` (VPU stages) and ``dm_ref`` (the
+    fold block; ``m_ref``/``dm_ref`` are None where the butterfly does not
+    fold)."""
+    folds = m_ref is not None
+    if folds and not transpose:
+        _fold(buf, m_ref)
+    top = None
+    if folds and transpose:
+        def top():
+            _fold_vjp(buf, gbuf, m_ref, dm_ref)
+    _butterfly_bwd_block(buf, gbuf, w_ref, lo, hi, transpose, segment,
+                         ckpt_ref, acts_ref, dw_ref, top)
+    if folds and not transpose:
+        _fold_vjp(x_ref, gbuf, m_ref, dm_ref)
+
+
 def _zero_on_first_step(*refs) -> None:
     """Zero accumulator outputs on the first grid step: the TPU grid is
-    sequential and every step revisits (and adds into) the same block."""
+    sequential and every step revisits (and adds into) the same block.
+    None entries (absent fold blocks) are skipped."""
     @pl.when(pl.program_id(0) == 0)
     def _():
         for r in refs:
-            r[...] = jnp.zeros_like(r)
+            if r is not None:
+                r[...] = jnp.zeros_like(r)
 
 
-def _butterfly_bwd_kernel(x_ref, w_ref, g_ref, dx_ref, dw_ref, buf, gbuf,
-                          ckpt_ref, acts_ref, *, stages: int,
+def _butterfly_bwd_kernel(x_ref, w_ref, g_ref, *refs, lo: int, hi: int,
                           transpose: bool, segment: int):
-    _zero_on_first_step(dw_ref)
+    if lo:
+        m_ref, dx_ref, dw_ref, dm_ref, *scratch = refs
+    else:
+        m_ref = dm_ref = None
+        dx_ref, dw_ref, *scratch = refs
+    buf, gbuf, ckpt_ref, acts_ref = scratch
+    _zero_on_first_step(dw_ref, dm_ref)
     _copy(buf, x_ref)
     _copy(gbuf, g_ref)
-    _butterfly_bwd_block(buf, gbuf, w_ref, stages, transpose, segment,
-                         ckpt_ref, acts_ref, dw_ref)
+    _butterfly_vjp(buf, gbuf, x_ref, w_ref, m_ref, dw_ref, dm_ref, lo, hi,
+                   transpose, segment, ckpt_ref, acts_ref)
     _copy(dx_ref, gbuf)
 
 
@@ -355,9 +477,47 @@ def tile_scratch(bb: int, n: int):
 
 def checkpoint_scratch(stages: int, seg: int, bb: int, n: int):
     """VMEM scratch of the segmented backward: segment-boundary checkpoints
-    and one segment's recomputed stage inputs, float32."""
-    return [pltpu.VMEM((len(range(0, stages, seg)), bb, n), jnp.float32),
+    and one segment's recomputed stage inputs, float32 (one tile each at
+    least, so a butterfly whose stages all fold keeps the kernel's
+    signature)."""
+    return [pltpu.VMEM((max(1, len(range(0, stages, seg))), bb, n),
+                       jnp.float32),
             pltpu.VMEM((seg, bb, n), jnp.float32)]
+
+
+def resident(shape):
+    """BlockSpec of a block every grid step revisits (weights, fold blocks
+    and their gradients): held in VMEM once, not double-buffered."""
+    return pl.BlockSpec(shape, lambda i: (0, 0),
+                        pipeline_mode=pl.Buffered(1))
+
+
+def fold_block(w: jnp.ndarray, transpose: bool) -> jnp.ndarray:
+    """The folded stages of the ``(p, 2, n)`` butterfly ``w`` as a float32
+    ``(128, n)`` array ``M``: column block ``g`` is the 128×128 matrix of
+    stages s = 0…6 on lane group ``g``, so that ``x[:, g] @ M[:, g]``
+    applies them. Built by running those stages, in the kernel's order and
+    form, over 128 copies of the identity: ``a⊙x + b⊙swap(x)`` for s = 0…6
+    (B applies them first), or ``a⊙x + swap(b⊙x)`` for s = 6…0 (Bᵀ applies
+    them last). Costs about 21·128·n operations, whatever the batch."""
+    n = w.shape[-1]
+    m = jnp.tile(jnp.eye(_LANES, dtype=jnp.float32), (1, n // _LANES))
+    w = w.astype(jnp.float32)
+    order = range(tuning.FOLD_STAGES)
+    for s in (reversed(order) if transpose else order):
+        a, b = w[s, 0], w[s, 1]
+        m = (a * m + stage_swap(b * m, 1 << s) if transpose
+             else a * m + b * stage_swap(m, 1 << s))
+    return m
+
+
+def fold_weight_grads(w: jnp.ndarray, transpose: bool,
+                      dm: jnp.ndarray) -> jnp.ndarray:
+    """float32 ``(p, 2, n)`` gradient of the folded stages' weights: the VJP
+    of :func:`fold_block` at the kernel's ``dM`` (zero past stage 6)."""
+    _, vjp = jax.vjp(lambda w32: fold_block(w32, transpose),
+                     w.astype(jnp.float32))
+    return vjp(dm)[0]
 
 
 def weight_rows(w: jnp.ndarray) -> jnp.ndarray:
@@ -396,64 +556,69 @@ def _butterfly_fwd_call(x: jnp.ndarray, w: jnp.ndarray, transpose: bool,
                         block_b, interpret: bool) -> jnp.ndarray:
     p, two, n = w.shape
     assert two == 2 and (1 << p) == n, f"bad weight shape {w.shape}"
-    stages = num_stages(n)
+    lo = tuning.folded_stages(n)
     block_b = tuning.resolve_block_b("butterfly", n, x.dtype, "fwd", block_b)
     x2, lead, b, bb, padded_b = _flatten_batch(x, block_b)
-    grid = (padded_b // bb,)
+    in_specs = [pl.BlockSpec((bb, n), lambda i: (i, 0)),
+                resident((_padded_rows(p), n))]
+    args = [x2, weight_rows(w)]
+    if lo:
+        in_specs.append(resident((_LANES, n)))
+        args.append(fold_block(w, transpose))
     out = pl.pallas_call(
-        functools.partial(_butterfly_kernel, stages=stages,
+        functools.partial(_butterfly_kernel, lo=lo, hi=num_stages(n),
                           transpose=transpose),
         name="butterfly_matmul_fwd",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-            pl.BlockSpec((_padded_rows(p), n), lambda i: (0, 0)),
-        ],
+        grid=(padded_b // bb,),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((bb, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded_b, n), x.dtype),
         scratch_shapes=[tile_scratch(bb, n)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=tuning.vmem_limit_bytes()),
         interpret=interpret,
-    )(x2, weight_rows(w))
+    )(*args)
     return out[:b].reshape(*lead, n)
 
 
 def _butterfly_bwd_call(x: jnp.ndarray, w: jnp.ndarray, g: jnp.ndarray,
                         transpose: bool, block_b, segment, interpret: bool):
+    """``(dx, dw, dM)``: the VPU stages' float32 ``(p, 2, n)`` weight
+    gradient and the fold block's (None where the butterfly does not
+    fold)."""
     p, _, n = w.shape
-    stages = num_stages(n)
+    lo = tuning.folded_stages(n)
     block_b = tuning.resolve_block_b("butterfly", n, x.dtype, "bwd", block_b)
-    seg = tuning.resolve_segment(stages, segment, kernel="butterfly", n=n,
+    seg = tuning.resolve_segment(p - lo, segment, kernel="butterfly", n=n,
                                  dtype=x.dtype)
     x2, lead, b, bb, padded_b = _flatten_batch(x, block_b)
     g2, _, _, _, _ = _flatten_batch(g.astype(x.dtype), block_b)
-    grid = (padded_b // bb,)
-    dx, dw = pl.pallas_call(
-        functools.partial(_butterfly_bwd_kernel, stages=stages,
+    tile = pl.BlockSpec((bb, n), lambda i: (i, 0))
+    in_specs = [tile, resident((_padded_rows(p), n)), tile]
+    out_specs = [tile, resident((_padded_rows(p), n))]
+    out_shape = [jax.ShapeDtypeStruct((padded_b, n), x.dtype),
+                 jax.ShapeDtypeStruct((_padded_rows(p), n), jnp.float32)]
+    args = [x2, weight_rows(w), g2]
+    if lo:
+        in_specs.append(resident((_LANES, n)))
+        out_specs.append(resident((_LANES, n)))
+        out_shape.append(jax.ShapeDtypeStruct((_LANES, n), jnp.float32))
+        args.append(fold_block(w, transpose))
+    dx, dw, *dm = pl.pallas_call(
+        functools.partial(_butterfly_bwd_kernel, lo=lo, hi=p,
                           transpose=transpose, segment=seg),
         name="butterfly_matmul_bwd",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-            pl.BlockSpec((_padded_rows(p), n), lambda i: (0, 0)),
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-            pl.BlockSpec((_padded_rows(p), n), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((padded_b, n), x.dtype),
-            jax.ShapeDtypeStruct((_padded_rows(p), n), jnp.float32),
-        ],
+        grid=(padded_b // bb,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=([tile_scratch(bb, n), tile_scratch(bb, n)]
-                        + checkpoint_scratch(stages, seg, bb, n)),
+                        + checkpoint_scratch(p - lo, seg, bb, n)),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=tuning.vmem_limit_bytes()),
         interpret=interpret,
-    )(x2, weight_rows(w), g2)
-    return dx[:b].reshape(*lead, n), weight_grads(dw, p)
+    )(*args)
+    return dx[:b].reshape(*lead, n), weight_grads(dw, p), (dm or [None])[0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
@@ -470,8 +635,10 @@ def _butterfly_diff_fwd(x, w, transpose, block_b, segment, interpret):
 
 def _butterfly_diff_bwd(transpose, block_b, segment, interpret, res, g):
     x, w = res
-    dx, dw = _butterfly_bwd_call(x, w, g, transpose, block_b, segment,
-                                 interpret)
+    dx, dw, dm = _butterfly_bwd_call(x, w, g, transpose, block_b, segment,
+                                     interpret)
+    if dm is not None:
+        dw = dw + fold_weight_grads(w, transpose, dm)
     return dx, dw.astype(w.dtype)
 
 

@@ -13,9 +13,14 @@ checkpoint segment length, see :mod:`repro.kernels.butterfly`) per
 ``(kernel, n, dtype, direction)`` from an analytic VMEM-footprint model plus
 the chip's row of :data:`repro.launch.roofline.DEVICE_SPECS`:
 
-* footprint model — double-buffered input/output tiles and float32 weights,
-  plus the float32 working tiles (1 forward; 2 + ``⌈p/seg⌉ + seg``
-  checkpoint/recompute tiles backward) must fit the VMEM budget;
+* footprint model — double-buffered input/output tiles, the float32 blocks
+  every grid step revisits (stage weights, fold blocks, their gradients;
+  held once), plus the float32 working tiles (1 forward; 2 + ``⌈p'/seg⌉ +
+  seg`` checkpoint/recompute tiles backward, ``p'`` the stages left on the
+  VPU) must fit the VMEM budget. A sandwich is modeled at both its widths;
+* the fold — :func:`folded_stages` decides, from ``n`` alone, whether a
+  butterfly's seven in-lane stages run as one 128×128 MXU block per lane
+  group (:mod:`repro.kernels.butterfly`); :class:`KernelChoice` records it;
 * roofline estimate — per-row FLOPs over peak FLOP/s vs per-row HBM bytes
   over HBM bandwidth; reported in :class:`KernelChoice` so benchmarks and
   the trainer can record *why* a block size was picked.
@@ -56,6 +61,8 @@ from repro.kernels import context as exctx
 from repro.launch import roofline
 
 __all__ = [
+    "FOLD_STAGES",
+    "folded_stages",
     "KernelChoice",
     "tune",
     "resolve_block_b",
@@ -83,23 +90,33 @@ VMEM_FRACTION = 0.75
 MIN_BLOCK_B = 8
 MAX_BLOCK_B = 1024
 
+# log2 of a vreg's 128 lanes: the butterfly stages that stay inside one
+# lane group, which the kernels fold into a 128x128 block per group
+FOLD_STAGES = 7
+_LANES = 1 << FOLD_STAGES
+
 
 @dataclass(frozen=True)
 class KernelChoice:
     """One autotuned kernel configuration (and why it was picked)."""
 
     kernel: str
-    n: int
+    n: int                    # input width
+    n_out: int                # output width (a sandwich's second butterfly)
     dtype: str
     mode: str                 # "fwd" | "bwd"
     block_b: int
     segment: int              # backward checkpoint segment (1 for fwd)
+    folded: Tuple[int, ...]   # stages on the MXU, per butterfly
     vmem_bytes: int           # modeled peak VMEM footprint at this choice
     est_us_per_row: float     # roofline lower bound per activation row
 
     def summary(self) -> str:
-        return (f"{self.kernel}/{self.mode} n={self.n} {self.dtype}: "
+        width = (f"n={self.n}" if self.kernel == "butterfly"
+                 else f"n={self.n}->{self.n_out}")
+        return (f"{self.kernel}/{self.mode} {width} {self.dtype}: "
                 f"block_b={self.block_b} segment={self.segment} "
+                f"folded={'/'.join(map(str, self.folded))} "
                 f"vmem={self.vmem_bytes / 2**20:.2f}MB "
                 f"roofline={self.est_us_per_row:.4f}us/row")
 
@@ -131,7 +148,22 @@ def vmem_budget() -> int:
     env = os.environ.get("REPRO_TUNE_VMEM_BUDGET", "").strip()
     if env:
         return int(env)
+    return _chip_budget()
+
+
+def _chip_budget() -> int:
     return int(device_spec().scoped_vmem_bytes * VMEM_FRACTION)
+
+
+def folded_stages(n: int) -> int:
+    """Stages of an ``n``-wide butterfly that run on the MXU: the seven
+    in-lane ones (strides 1…64) where ``n ≥ 128`` and their float32
+    ``(128, n)`` block, double-buffered with its gradient, fits the chip's
+    VMEM budget (every width up to 4096 on v5e); none otherwise. A rule on
+    the shape, not a knob: the budget overrides do not move it."""
+    if n >= _LANES and 4 * _LANES * n * 4 <= _chip_budget():
+        return FOLD_STAGES
+    return 0
 
 
 def default_segment(stages: int) -> int:
@@ -166,68 +198,84 @@ def _weight_bytes(n: int, stages: int) -> int:
     return -(-2 * stages // 8) * 8 * n * 4
 
 
-def _footprint(kernel: str, n: int, dtype_name: str, stages: int,
+def _vpu_stages(n: int) -> int:
+    return num_stages(n) - folded_stages(n)
+
+
+def _butterfly_bytes(n: int, block_b: int, segment: int, mode: str) -> int:
+    """One butterfly's VMEM beyond the kernel's input/output tiles: its
+    stage weights and fold block (with their gradients backward), each held
+    once, and its float32 working tiles — 1 forward; backward the
+    activation and the cotangent plus ``⌈p'/seg⌉ + seg`` checkpoint and
+    recompute tiles. Traced values are one chunk wide and not counted."""
+    p = num_stages(n)
+    stages = _vpu_stages(n)
+    blocks = _weight_bytes(n, p) + (_LANES * n * 4 if folded_stages(n) else 0)
+    tile32 = block_b * n * 4
+    if mode == "fwd":
+        return blocks + tile32
+    seg = max(1, min(segment, stages))
+    return 2 * blocks + (2 + max(1, -(-stages // seg)) + seg) * tile32
+
+
+def _footprint(kernel: str, n: int, n_out: int, dtype_name: str,
                block_b: int, segment: int, mode: str) -> int:
     """Modeled peak VMEM bytes for one grid step.
 
-    Input/output tiles and weights are double-buffered by the pipeline, as
-    are the backward's float32 weight-gradient outputs. The stages update
-    a float32 working tile in place (the backward adds the cotangent's tile
-    and ``⌈p/seg⌉ + seg`` checkpoint/recompute tiles); traced values are
-    one chunk wide and not counted.
+    Input/output tiles are double-buffered by the pipeline (``x``/``out``
+    forward; ``x``/``g``/``dx`` backward); each butterfly adds
+    :func:`_butterfly_bytes`; a sandwich adds its one-hot selections and
+    core (and the core's gradient), held once and padded to whole (8, 128)
+    float32 tiles.
     """
-    tile_io = block_b * n * _itemsize(dtype_name)
-    tile32 = block_b * n * 4
-    w = _weight_bytes(n, stages)
-    if mode == "fwd":
-        io, weights, work = 4 * tile_io, 2 * w, tile32
-    else:
-        n_ckpt = -(-stages // segment)
-        io, weights = 6 * tile_io, 4 * w
-        work = (2 + n_ckpt + min(segment, stages)) * tile32
-    total = io + weights + work
-    if kernel == "sandwich":
-        # the second butterfly's weights, gradients and working tiles,
-        # modeled at the same n — the tuner is called with max(n1, n2),
-        # conservative for the smaller side — plus the selections and core
-        # (paper's k = log2 n), double-buffered
-        k = max(2, stages)
-        total += weights + work + 2 * (2 * n * k + k * k) * 4
-    return total
+    item = _itemsize(dtype_name)
+    tiles_in, tiles_out = (2, 2) if mode == "fwd" else (4, 2)
+    total = block_b * item * (tiles_in * n + tiles_out * n_out)
+    if kernel == "butterfly":
+        return total + _butterfly_bytes(n, block_b, segment, mode)
+    k_in, k_out = max(2, num_stages(n)), max(2, num_stages(n_out))
+    core = -(-k_out // 8) * 8 * -(-k_in // _LANES) * _LANES * 4
+    sel = (n * -(-k_in // _LANES) * _LANES + -(-k_out // 8) * 8 * n_out) * 4
+    return (total + _butterfly_bytes(n, block_b, segment, mode)
+            + _butterfly_bytes(n_out, block_b, segment, mode)
+            + sel + core * (2 if mode == "bwd" else 1))
 
 
-def _roofline_us_per_row(kernel: str, n: int, dtype_name: str,
-                         stages: int, mode: str) -> float:
+def _roofline_us_per_row(kernel: str, n: int, n_out: int, dtype_name: str,
+                         mode: str) -> float:
     """max(compute, memory) roofline time per activation row, in µs."""
     item = _itemsize(dtype_name)
-    # one stage = 2 mul + 1 add per element; backward ~3x (recompute sweep +
-    # dual sweep + weight-grad reductions)
-    stage_flops = 3.0 * n * stages
-    flops = stage_flops * (3.0 if mode == "bwd" else 1.0)
-    if kernel == "sandwich":
-        flops *= 2.0
-    hbm = 2.0 * n * item * (2.0 if mode == "bwd" else 1.0)
+    widths = (n,) if kernel == "butterfly" else (n, n_out)
+    # one VPU stage = 2 mul + 1 add per element; the fold block 2·128
+    # per element; backward ~3x (recompute + cotangent + weight gradients)
+    flops = sum(3.0 * w * _vpu_stages(w)
+                + (2.0 * _LANES * w if folded_stages(w) else 0.0)
+                for w in widths)
+    flops *= 3.0 if mode == "bwd" else 1.0
+    hbm = (n + n_out) * item * (1.5 if mode == "bwd" else 1.0)
     spec = device_spec()
     return max(flops / spec.peak_flops, hbm / spec.hbm_bw) * 1e6
 
 
 @functools.lru_cache(maxsize=None)
-def _tune_cached(kernel: str, n: int, dtype_name: str, mode: str,
-                 budget: int) -> KernelChoice:
-    """Pick (block_b, segment) for one (kernel, n, dtype, direction) cell.
+def _tune_cached(kernel: str, n: int, n_out: int, dtype_name: str,
+                 mode: str, budget: int) -> KernelChoice:
+    """Pick (block_b, segment) for one (kernel, widths, dtype, direction)
+    cell.
 
     Largest power-of-two ``block_b`` whose modeled footprint fits the VMEM
     budget, floored at the dtype's sublane minimum; ``segment`` scans the
-    neighborhood of ⌈√p⌉ for the smallest live-tile count (ties go to the
-    larger segment: fewer checkpoint writes). ``budget`` is part of the
-    cache key so a changed ``REPRO_TUNE_VMEM_BUDGET`` is never served a
-    stale choice.
+    neighborhood of ⌈√p'⌉ (``p'``: the deeper butterfly's VPU stages) for
+    the smallest live-tile count (ties go to the larger segment: fewer
+    checkpoint writes). ``budget`` is part of the cache key so a changed
+    ``REPRO_TUNE_VMEM_BUDGET`` is never served a stale choice.
     """
     if kernel not in ("butterfly", "sandwich"):
         raise ValueError(f"unknown tunable kernel {kernel!r}")
     if mode not in ("fwd", "bwd"):
         raise ValueError(f"unknown mode {mode!r}")
-    stages = num_stages(n)
+    widths = (n,) if kernel == "butterfly" else (n, n_out)
+    stages = max(max(_vpu_stages(w) for w in widths), 1)
 
     seg0 = default_segment(stages)
     if mode == "bwd":
@@ -240,44 +288,48 @@ def _tune_cached(kernel: str, n: int, dtype_name: str, mode: str,
     floor = _min_block_b(dtype_name)
     b = MAX_BLOCK_B
     while b >= floor:
-        if _footprint(kernel, n, dtype_name, stages, b, segment,
+        if _footprint(kernel, n, n_out, dtype_name, b, segment,
                       mode) <= budget:
             break
         b //= 2
     block_b = max(b, floor)
 
     return KernelChoice(
-        kernel=kernel, n=n, dtype=dtype_name, mode=mode,
+        kernel=kernel, n=n, n_out=n_out, dtype=dtype_name, mode=mode,
         block_b=block_b, segment=segment,
-        vmem_bytes=_footprint(kernel, n, dtype_name, stages, block_b,
+        folded=tuple(folded_stages(w) for w in widths),
+        vmem_bytes=_footprint(kernel, n, n_out, dtype_name, block_b,
                               segment, mode),
-        est_us_per_row=_roofline_us_per_row(kernel, n, dtype_name, stages,
+        est_us_per_row=_roofline_us_per_row(kernel, n, n_out, dtype_name,
                                             mode))
 
 
 def resolve_block_b(kernel: str, n: int, dtype, mode: str,
-                    override: Optional[int] = None) -> int:
+                    override: Optional[int] = None,
+                    n_out: Optional[int] = None) -> int:
     """Concrete batch-tile rows: explicit override > env > autotuner."""
     if override is not None:
         return int(override)
     env = os.environ.get("REPRO_TUNE_BLOCK_B", "").strip()
     if env:
         return int(env)
-    return tune(kernel, n, jnp.dtype(dtype).name, mode).block_b
+    return tune(kernel, n, jnp.dtype(dtype).name, mode, n_out).block_b
 
 
 def resolve_segment(stages: int, override: Optional[int] = None,
                     kernel: str = "butterfly", n: Optional[int] = None,
-                    dtype=jnp.float32) -> int:
-    """Concrete checkpoint segment length, clamped to [1, stages]."""
+                    dtype=jnp.float32, n_out: Optional[int] = None) -> int:
+    """Concrete checkpoint segment length for ``stages`` VPU stages,
+    clamped to [1, stages]."""
     if override is not None:
-        return max(1, min(int(override), max(stages, 1)))
-    env = os.environ.get("REPRO_TUNE_SEGMENT", "").strip()
-    if env:
-        return max(1, min(int(env), max(stages, 1)))
-    if n is not None:
-        return tune(kernel, n, jnp.dtype(dtype).name, "bwd").segment
-    return default_segment(stages)
+        seg = int(override)
+    elif os.environ.get("REPRO_TUNE_SEGMENT", "").strip():
+        seg = int(os.environ["REPRO_TUNE_SEGMENT"])
+    elif n is not None:
+        seg = tune(kernel, n, jnp.dtype(dtype).name, "bwd", n_out).segment
+    else:
+        seg = default_segment(stages)
+    return max(1, min(seg, max(stages, 1)))
 
 
 def flash_blocks(seq_len: int, head_dim: int, dtype_name: str,
@@ -329,12 +381,16 @@ def _flash_blocks_cached(seq_len: int, head_dim: int, dtype_name: str,
 _CHOICES: Dict[str, str] = {}
 
 
-def tune(kernel: str, n: int, dtype_name: str, mode: str = "fwd"
-         ) -> KernelChoice:
+def tune(kernel: str, n: int, dtype_name: str, mode: str = "fwd",
+         n_out: Optional[int] = None) -> KernelChoice:
+    """The choice for one kernel call: ``n`` is the input width, ``n_out``
+    a sandwich's output width (default ``n``)."""
+    n_out = n if n_out is None or kernel == "butterfly" else n_out
     # env (budget) is read here, outside the cache, so overrides set after
     # the first query still take effect
-    choice = _tune_cached(kernel, n, dtype_name, mode, vmem_budget())
-    _CHOICES[f"{kernel}/{mode}/n{n}/{dtype_name}"] = choice.summary()
+    choice = _tune_cached(kernel, n, n_out, dtype_name, mode, vmem_budget())
+    width = f"n{n}" if n_out == n else f"n{n}-{n_out}"
+    _CHOICES[f"{kernel}/{mode}/{width}/{dtype_name}"] = choice.summary()
     return choice
 
 

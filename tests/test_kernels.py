@@ -15,7 +15,7 @@ from repro.kernels import ops, ref
 from repro.kernels.sandwich import one_hot_select
 
 
-@pytest.mark.parametrize("n", [8, 64, 512])
+@pytest.mark.parametrize("n", [8, 64, 512, 128])
 @pytest.mark.parametrize("batch", [1, 3, 300])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_butterfly_kernel_forward(n, batch, dtype):
@@ -29,10 +29,12 @@ def test_butterfly_kernel_forward(n, batch, dtype):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("n", [16, 128])
+@pytest.mark.parametrize("n", [16, 128, 256, 1024, 2048])
 @pytest.mark.parametrize("transpose", [False, True])
 def test_butterfly_kernel_transpose_and_grid(n, transpose):
-    """Batch larger than one grid block exercises the BlockSpec tiling."""
+    """Batch larger than one grid block exercises the BlockSpec tiling; from
+    n = 128 on, the in-lane stages run as the folded MXU block (all of them
+    at 128, all but one at 256)."""
     w = bf.random_weights(jax.random.PRNGKey(2), n)
     x = jax.random.normal(jax.random.PRNGKey(3), (700, n))
     got = ops.butterfly_apply(x, w, transpose=transpose,
@@ -54,7 +56,7 @@ def test_butterfly_kernel_nd_batch():
 
 
 @pytest.mark.parametrize("n1,n2,k1,k2", [(64, 64, 8, 8), (128, 256, 16, 12),
-                                         (32, 128, 32, 16)])
+                                         (32, 128, 32, 16), (256, 128, 8, 7)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_sandwich_kernel_vs_layer(n1, n2, k1, k2, dtype):
     """Fused sandwich kernel == ButterflyLinear layer (the jnp production

@@ -151,7 +151,8 @@ def test_sharded_butterfly_under_jit_ambient_context():
 
 def _sandwich_case(mesh_shape, batch, backend):
     ctx = _ctx(backend, mesh_shape)
-    n1, n2, k1, k2 = 32, 64, 8, 6
+    # 128 folds every stage of b_in onto the MXU, 256 all but one of b_out
+    n1, n2, k1, k2 = 128, 256, 8, 6
     spec = bl.make_spec(jax.random.PRNGKey(7), n1, n2, k_in=k1, k_out=k2,
                         use_bias=False)
     params = bl.init_butterfly_linear(jax.random.PRNGKey(8), spec)

@@ -104,3 +104,25 @@ def test_tune_registry_and_describe():
 def test_bf16_sublane_floor():
     c = tuning.tune("butterfly", 256, "bfloat16", "fwd")
     assert c.block_b >= 16                               # bf16 min sublane
+
+
+@pytest.mark.parametrize("n_in,n_out", [(1024, 2048), (2048, 1024),
+                                        (1024, 65536)])
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+def test_fold_follows_width(n_in, n_out, mode):
+    """SmolLM-135M's sandwich sites: every MLP side (1024, 2048) and the LM
+    head's input side fold their seven in-lane stages onto the MXU; the
+    head's 65,536-wide output cannot hold its (128, n) block in VMEM and
+    keeps every stage on the VPU. The choice shows in ``describe()`` and
+    the MLP sites' modeled footprint fits the budget."""
+    c = tuning.tune("sandwich", n_in, "bfloat16", mode, n_out)
+    want = tuple(7 if n <= 4096 else 0 for n in (n_in, n_out))
+    assert c.folded == want
+    assert f"folded={want[0]}/{want[1]}" in tuning.describe()
+    if n_out <= 4096:
+        assert c.vmem_bytes <= tuning.vmem_budget(), c.summary()
+        assert c.segment <= bf.num_stages(max(n_in, n_out)) - 7
+    assert tuning.tune("butterfly", n_in, "bfloat16", mode).folded == (7,)
+    assert tuning.folded_stages(65536) == 0
+    assert [tuning.folded_stages(n) for n in (64, 128, 4096, 8192)] == [
+        0, 7, 7, 0]
